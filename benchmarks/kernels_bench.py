@@ -7,6 +7,8 @@ import time
 import jax
 import jax.numpy as jnp
 
+from repro.utils.compile_cache import place_compile_cache
+
 
 def _t(fn, *args, iters=3):
     fn(*args)[0].block_until_ready() if isinstance(fn(*args), tuple) else \
@@ -82,6 +84,7 @@ def run():
 
 
 def main():
+    place_compile_cache()
     for r in run():
         bps = f"{r['bytes_per_s']:.3g}" if "bytes_per_s" in r else "-"
         print(f"{r['bench']},{r['column']},{r['layer']},{r['kind']},"

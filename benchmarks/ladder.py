@@ -71,6 +71,7 @@ import numpy as np
 
 from repro.core import Engine, EngineConfig, Request, UpstreamEngine
 from repro.core.blockdev import VolumeManager
+from repro.utils.compile_cache import place_compile_cache
 
 COLUMNS = ("upstream", "+frontend", "+comm", "+dbs", "+fused", "+sharded",
            "+ring")
@@ -487,14 +488,18 @@ def run_kernels(*, repeats: int = 3, **_ignored) -> Dict[str, Any]:
     interpret-mode Pallas wall times measure the interpreter, not the
     kernel, so that ratio is only taken where the kernel compiles).
     Lands in BENCH json under ``kernels``; ``benchmarks/roofline.py``
-    renders achieved-vs-peak bytes/s from it."""
+    renders achieved-vs-peak bytes/s from it. Shares of a device peak are
+    only recorded where the kernels run compiled: a CPU wall time is not a
+    device number."""
     from repro.core import dbs
     from repro.kernels.dbs import (dbs_read_bytes, dbs_write_bytes,
                                    make_kernel)
     from repro.kernels.dbs.registry import available_kernels
+    from repro.kernels.platform import default_interpret
     from repro.utils.machine import machine_profile
 
-    prof = machine_profile()
+    on_chip = not default_interpret()
+    prof = machine_profile() if on_chip else None
     e, page, d, b = 129, 8, 32, 32          # +1 reserved scratch row
     ks = jax.random.split(jax.random.PRNGKey(7), 2)
     pool = jax.random.normal(ks[0], (e, page, d))
@@ -526,7 +531,7 @@ def run_kernels(*, repeats: int = 3, **_ignored) -> Dict[str, Any]:
     ref_w = xla.write(pool, dbs.WriteOps(dst=dst, cow_src=cow_src, ok=ok),
                       payload, blocks)
     ref_r = xla.read(pool, ext, blocks)
-    out: Dict[str, Any] = {"profile": prof.to_dict()}
+    out: Dict[str, Any] = {"profile": prof.to_dict() if prof else None}
     for name in available_kernels():
         kern = make_kernel(name)
         wf = jax.jit(lambda p, pay, dd, cc, oo, bl, k=kern: k.write(
@@ -544,11 +549,13 @@ def run_kernels(*, repeats: int = 3, **_ignored) -> Dict[str, Any]:
             "write_us": w_us, "read_us": r_us,
             "write_bytes_per_s": wbytes / (w_us * 1e-6),
             "read_bytes_per_s": rbytes / (r_us * 1e-6),
-            "write_vs_peak": wbytes / (w_us * 1e-6) / prof.hbm_bw,
-            "read_vs_peak": rbytes / (r_us * 1e-6) / prof.hbm_bw,
+            "write_vs_peak": (wbytes / (w_us * 1e-6) / prof.hbm_bw
+                              if prof else None),
+            "read_vs_peak": (rbytes / (r_us * 1e-6) / prof.hbm_bw
+                             if prof else None),
             "identical": identical,
         }
-    if jax.default_backend() == "tpu":      # the compiled-only perf ratio
+    if on_chip:                             # the compiled-only perf ratio
         pay = jnp.ones((16,), jnp.float32)
         for kname in ("pallas", "xla"):
             eng = make_engine("+fused", "full_engine", payload_shape=(16,),
@@ -1067,6 +1074,7 @@ def main(argv=None) -> int:
                          "kernels,serve,compute,durability); default runs "
                          "everything")
     args = ap.parse_args(argv)
+    place_compile_cache()
 
     sections = ("ladder", "mixed", "blockdev", "replication", "trace",
                 "kernels", "serve", "compute", "durability")
@@ -1130,9 +1138,11 @@ def main(argv=None) -> int:
             f"r={row['read_bytes_per_s']:.3g}B/s ok={row['identical']}"
             for name, row in kernels.items()
             if isinstance(row, dict) and "write_us" in row)
+        prof = kernels["profile"]
         print("dbs kernels (registry; nominal achieved bytes/s + "
               "bit-identity vs the xla reference; profile "
-              f"{kernels['profile']['name']}): {kern_cells}")
+              f"{prof['name'] if prof else 'none: interpret mode'}): "
+              f"{kern_cells}")
     if serve is not None:
         print("serving (zero-copy KV-on-volumes vs copy-based host "
               "baseline; sessions/s + per-token wall P99): zero-copy "

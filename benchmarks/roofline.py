@@ -1,12 +1,14 @@
 """Roofline table renderer: reads dry-run JSONs and prints the per-cell
 three-term analysis (EXPERIMENTS.md §Roofline is generated from this).
 
-Peaks come from ``repro.utils.machine.machine_profile`` — detected from the
-jax device kind, overridable with ``--peak-flops``/``--hbm-bw``/``--link-bw``
-(or ``REPRO_PEAK_FLOPS``/``REPRO_HBM_BW``/``REPRO_LINK_BW``), falling back
-to the v5e assignment-brief numbers — so fractions aren't silently wrong off
-the original TPU box. A ladder ``BENCH_*.json`` (its ``kernels`` key) renders
-as the per-kernel achieved-vs-peak bytes/s table instead.
+Peaks come from ``repro.utils.machine.machine_profile`` — the published
+peaks of the jax device kind, overridable with
+``--peak-flops``/``--hbm-bw``/``--link-bw`` (or
+``REPRO_PEAK_FLOPS``/``REPRO_HBM_BW``/``REPRO_LINK_BW``); a device without
+published peaks must be given all three. A ladder ``BENCH_*.json`` (its
+``kernels`` key) renders as the per-kernel achieved bytes/s table instead,
+with peak shares only against the profile the ladder recorded on the chip
+(the peak flags apply to the dry-run table alone).
 """
 from __future__ import annotations
 
@@ -14,12 +16,6 @@ import json
 from typing import Dict, List, Optional
 
 from repro.utils.machine import MachineProfile, machine_profile
-
-# back-compat module constants (the v5e defaults); consumers should resolve
-# a MachineProfile instead
-PEAK_FLOPS = 197e12
-HBM_BW = 819e9
-LINK_BW = 50e9
 
 
 def load(path: str):
@@ -29,11 +25,10 @@ def load(path: str):
 
 def render(results: List[dict], *, only_single_pod: bool = True,
            profile: Optional[MachineProfile] = None) -> str:
-    prof = profile or machine_profile()
+    # the dry run computes its terms against the v5e peaks (launch/dryrun.py)
+    prof = profile or machine_profile(device_kind="TPU v5 lite")
     lines = [f"profile: {prof.name}  peak_flops={prof.peak_flops:.3g}  "
-             f"hbm_bw={prof.hbm_bw:.3g}  link_bw={prof.link_bw:.3g}"
-             + ("  (ASSUMED — pass --peak-flops/--hbm-bw or set "
-                "REPRO_* env)" if prof.assumed else "")]
+             f"hbm_bw={prof.hbm_bw:.3g}  link_bw={prof.link_bw:.3g}"]
     hdr = (f"{'arch:shape':44s} {'kind':8s} {'t_comp(s)':>10s} {'t_mem(s)':>10s}"
            f" {'t_coll(s)':>10s} {'bottleneck':>11s} {'useful':>7s} {'roofl':>6s}")
     lines.append(hdr)
@@ -58,35 +53,30 @@ def render(results: List[dict], *, only_single_pod: bool = True,
     return "\n".join(lines)
 
 
-def render_kernels(kernels: Dict[str, dict], *,
-                   profile: Optional[MachineProfile] = None) -> str:
-    """The ladder BENCH json's ``kernels`` key as an achieved-vs-peak
-    bytes/s table (one row per registered DBS kernel)."""
-    prof = profile or machine_profile()
-    if isinstance(kernels.get("profile"), dict):
-        p = kernels["profile"]
-        prof = MachineProfile(p.get("name", prof.name),
-                              p.get("peak_flops", prof.peak_flops),
-                              p.get("hbm_bw", prof.hbm_bw),
-                              p.get("link_bw", prof.link_bw),
-                              p.get("assumed", prof.assumed))
-    lines = [f"profile: {prof.name}  hbm_bw={prof.hbm_bw:.3g} B/s"
-             + ("  (ASSUMED)" if prof.assumed else "")]
-    hdr = (f"{'kernel':10s} {'write us':>9s} {'write B/s':>11s} "
-           f"{'vs peak':>8s} {'read us':>9s} {'read B/s':>11s} "
-           f"{'vs peak':>8s} {'identical':>9s}")
+def render_kernels(kernels: Dict[str, dict]) -> str:
+    """The ladder BENCH json's ``kernels`` key as an achieved bytes/s table
+    (one row per registered DBS kernel). Shares of the HBM peak are shown
+    only when the json carries the profile of the chip the kernels ran on
+    compiled; interpret-mode wall times get no peak column."""
+    p = kernels.get("profile")
+    prof = MachineProfile(**p) if isinstance(p, dict) else None
+    lines = [f"profile: {prof.name}  hbm_bw={prof.hbm_bw:.3g} B/s" if prof
+             else "profile: none (interpret mode; no peak shares)"]
+    peak = f" {'vs peak':>8s}" if prof else ""
+    hdr = (f"{'kernel':10s} {'write us':>9s} {'write B/s':>11s}{peak} "
+           f"{'read us':>9s} {'read B/s':>11s}{peak} {'identical':>9s}")
     lines.append(hdr)
     lines.append("-" * len(hdr))
     for name in sorted(kernels):
         row = kernels[name]
         if not isinstance(row, dict) or "write_us" not in row:
             continue
+        w_bps, r_bps = row["write_bytes_per_s"], row["read_bytes_per_s"]
+        w_peak = f" {w_bps / prof.hbm_bw:8.2e}" if prof else ""
+        r_peak = f" {r_bps / prof.hbm_bw:8.2e}" if prof else ""
         lines.append(
-            f"{name:10s} {row['write_us']:9.1f} "
-            f"{row['write_bytes_per_s']:11.3g} "
-            f"{row['write_bytes_per_s'] / prof.hbm_bw:8.2e} "
-            f"{row['read_us']:9.1f} {row['read_bytes_per_s']:11.3g} "
-            f"{row['read_bytes_per_s'] / prof.hbm_bw:8.2e} "
+            f"{name:10s} {row['write_us']:9.1f} {w_bps:11.3g}{w_peak} "
+            f"{row['read_us']:9.1f} {r_bps:11.3g}{r_peak} "
             f"{str(row.get('identical', '-')):>9s}")
     return "\n".join(lines)
 
@@ -103,14 +93,14 @@ def main():
     ap.add_argument("--link-bw", type=float, default=None,
                     help="override ICI bytes/s per link")
     args = ap.parse_args()
-    prof = machine_profile(args.peak_flops, args.hbm_bw, args.link_bw)
     doc = load(args.json)
-    if isinstance(doc, dict) and "kernels" in doc:        # a ladder BENCH json
-        print(render_kernels(doc["kernels"], profile=prof))
-    elif isinstance(doc, dict):
-        print(render_kernels(doc, profile=prof))
-    else:
-        print(render(doc, only_single_pod=not args.all_meshes, profile=prof))
+    if isinstance(doc, dict):           # a ladder BENCH json or its kernels
+        print(render_kernels(doc.get("kernels", doc)))
+        return
+    explicit = (args.peak_flops, args.hbm_bw, args.link_bw)
+    prof = (machine_profile(*explicit)
+            if any(v is not None for v in explicit) else None)
+    print(render(doc, only_single_pod=not args.all_meshes, profile=prof))
 
 
 if __name__ == "__main__":

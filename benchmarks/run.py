@@ -10,8 +10,11 @@ from __future__ import annotations
 
 import os
 
+from repro.utils.compile_cache import place_compile_cache
+
 
 def main() -> None:
+    place_compile_cache()
     print("name,us_per_call,derived")
     from benchmarks import table1_iops, table2_bandwidth, kernels_bench
     for r in table1_iops.run(n_requests=256):

@@ -2,6 +2,7 @@
 from __future__ import annotations
 
 from benchmarks.ladder import ROWS, COLUMNS, run_ladder, snapshot_degradation
+from repro.utils.compile_cache import place_compile_cache
 
 
 def run(n_requests: int = 384) -> list:
@@ -27,6 +28,7 @@ def run(n_requests: int = 384) -> list:
 
 
 def main():
+    place_compile_cache()
     for r in run():
         print(f"{r['bench']},{r['column']},{r['layer']},{r['kind']},"
               f"{r['us_per_call']:.1f},{r['ops_per_s']:.0f}")

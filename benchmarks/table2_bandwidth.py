@@ -9,6 +9,7 @@ import numpy as np
 
 from benchmarks.ladder import COLUMNS, ROWS, make_engine
 from repro.core import Request
+from repro.utils.compile_cache import place_compile_cache
 
 # one "1 MB extent" analogue: page_blocks x block payload
 PAGE_BLOCKS = 32
@@ -62,6 +63,7 @@ def run(n_extents_io: int = 64, warmup: bool = True) -> List[dict]:
 
 
 def main():
+    place_compile_cache()
     for r in run():
         print(f"{r['bench']},{r['column']},{r['layer']},{r['kind']},"
               f"{r['us_per_call']:.1f},{r['mb_per_s']:.1f}")

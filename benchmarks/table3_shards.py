@@ -27,6 +27,7 @@ from typing import Dict, Iterable, List
 import jax.numpy as jnp
 
 from benchmarks.ladder import make_engine, measure_engine
+from repro.utils.compile_cache import place_compile_cache
 
 SHARDS = (1, 2, 4, 8)
 
@@ -86,6 +87,7 @@ def main(argv=None) -> int:
                     help="exit 1 if sharding loses to the fused baseline "
                          "or to fewer shards (see check_scaling)")
     args = ap.parse_args(argv)
+    place_compile_cache()
 
     kw = (dict(shards=(1, 2, 4), n_requests=512) if args.smoke
           else dict(shards=SHARDS))

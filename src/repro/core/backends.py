@@ -153,8 +153,6 @@ class _FrontendBackendBase(ControlDispatch):
                 null_storage=cfg.null_storage, transport=cfg.transport,
                 write_policy=cfg.write_policy, read_policy=cfg.read_policy,
                 transport_opts=cfg.transport_opts)
-        self._cow = (cfg.cow if cfg.cow != "auto" else
-                     ("pallas" if jax.default_backend() == "tpu" else "ref"))
         from repro.kernels.dbs.registry import resolve_kernel_name
         self._kernel = resolve_kernel_name(cfg)
         self.completed = 0

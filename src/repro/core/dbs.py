@@ -283,9 +283,28 @@ def write_pages(st: DBSState, vol: jnp.ndarray, pages: jnp.ndarray,
 @jax.tree_util.register_dataclass
 @dataclass
 class WriteOps:
+    """One write batch's data-plane orders, as ``write_pages`` emits them.
+
+    Two conventions every data plane may rely on: ``cow_src`` sits only on
+    the first live lane of each ``dst`` group (the group leader), and a CoW
+    source is never a live lane's destination in the same batch — sources
+    are owned by a frozen snapshot, destinations by the live head or the
+    free ring. The ``xla`` reference reads every source before writing any
+    row; the compiled Pallas kernels (``dbs_rw_write``, ``dbs_copy``)
+    stream rows, so a source written earlier in the same batch would read
+    back the new bytes.
+    """
     dst: jnp.ndarray       # (B,) destination extents (-1 = failed/starved)
     cow_src: jnp.ndarray   # (B,) source extents to copy first (-1 = none)
     ok: jnp.ndarray        # (B,) bool
+
+    def live(self) -> jnp.ndarray:
+        """Lanes that write: ``ok`` AND a real destination. ``dst = -1``
+        marks a failed or starved lane and wins over ``ok``, so such a lane
+        writes nothing — no CoW copy, no block store. ``write_pages`` never
+        emits one; hand-built batches may, and every data plane (the
+        ``xla`` reference and the kernels' routing) reads them this way."""
+        return self.ok & (self.dst >= 0)
 
 
 def apply_write_ops(pool: jnp.ndarray, ops: WriteOps,
@@ -296,9 +315,10 @@ def apply_write_ops(pool: jnp.ndarray, ops: WriteOps,
     pool: (E, page, ...); payload: (B, ...) one block per lane;
     block_offsets: (B,) position of the written block within its page.
     """
+    live = ops.live()
     safe_dst = jnp.maximum(ops.dst, 0)
     safe_src = jnp.maximum(ops.cow_src, 0)
-    do_copy = (ops.cow_src >= 0) & ops.ok
+    do_copy = (ops.cow_src >= 0) & live
     # only COPY lanes touch the whole-extent scatter: a write-back of the
     # "current" extent value is NOT inert when another lane of the batch
     # shares the destination (grouped same-page writes, see write_pages) —
@@ -306,7 +326,7 @@ def apply_write_ops(pool: jnp.ndarray, ops: WriteOps,
     # non-copy lanes scatter out of bounds and are dropped.
     drop_copy = jnp.where(do_copy, safe_dst, pool.shape[0])
     pool = pool.at[drop_copy].set(pool[safe_src], mode="drop")
-    drop_dst = jnp.where(ops.ok, safe_dst, pool.shape[0])
+    drop_dst = jnp.where(live, safe_dst, pool.shape[0])
     pool = pool.at[drop_dst, block_offsets].set(payload, mode="drop")
     return pool
 

@@ -133,7 +133,6 @@ class Engine:
                      else None)
         self.frontend = self._impl.frontend
         self.backend = self._impl.storage
-        self._cow = getattr(self._impl, "_cow", None)
         self._kernel = getattr(self._impl, "_kernel", None)
 
     @property

@@ -645,8 +645,6 @@ class RingEngine(ControlDispatch):
                 write_policy=cfg.write_policy, read_policy=cfg.read_policy,
                 transport_opts=cfg.transport_opts)
         self.cq = make_sharded_cq(s, cfg.n_slots, cfg.payload_shape)
-        self._cow = (cfg.cow if cfg.cow != "auto" else
-                     ("pallas" if jax.default_backend() == "tpu" else "ref"))
         from repro.kernels.dbs.registry import resolve_kernel_name
         self._kernel = resolve_kernel_name(cfg)
         self._vol_rr = 0

@@ -95,8 +95,6 @@ class EnginePool(ControlDispatch):
                 null_storage=cfg.null_storage, transport=cfg.transport,
                 write_policy=cfg.write_policy, read_policy=cfg.read_policy,
                 transport_opts=cfg.transport_opts)
-        self._cow = (cfg.cow if cfg.cow != "auto" else
-                     ("pallas" if jax.default_backend() == "tpu" else "ref"))
         from repro.kernels.dbs.registry import resolve_kernel_name
         self._kernel = resolve_kernel_name(cfg)
         self._vol_rr = 0

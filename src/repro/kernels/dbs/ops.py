@@ -1,7 +1,7 @@
 """The DBS kernel family's shared ops surface.
 
-One module serves both kernels: ``default_interpret`` (the repo's
-TPU-or-interpret convention), the pure shape-adapting pool wrappers the
+One module serves both kernels: ``default_interpret`` (the interpret rule
+of ``repro.kernels.platform``), the pure shape-adapting pool wrappers the
 engine step traces inline (``dbs_copy_pool``, ``dbs_rw_write_pool``,
 ``dbs_rw_read_pool``), and the nominal-bytes accounting the roofline gate
 charges each kernel with. See docs/KERNELS.md for the grid/BlockSpec design
@@ -17,15 +17,7 @@ import jax.numpy as jnp
 from repro.kernels.dbs.copy_kernel import dbs_copy as _dbs_copy_kernel
 from repro.kernels.dbs.ref import dbs_copy_ref
 from repro.kernels.dbs.rw_kernel import dbs_rw_read, dbs_rw_write
-
-
-def default_interpret() -> bool:
-    """Repo convention: Pallas kernels run compiled on TPU and fall back to
-    ``interpret=True`` everywhere else (docs/KERNELS.md)."""
-    return jax.default_backend() != "tpu"
-
-
-_use_interpret = default_interpret  # back-compat alias
+from repro.kernels.platform import default_interpret
 
 
 @partial(jax.jit, static_argnames=("interpret",))
@@ -99,7 +91,7 @@ def _route_writes(ops, page, block_offsets, dump):
     """
     b = ops.dst.shape[0]
     arange = jnp.arange(b, dtype=jnp.int32)
-    ok = ops.ok & (ops.dst >= 0)
+    ok = ops.live()
     same = ok[None, :] & ok[:, None] & (ops.dst[None, :] == ops.dst[:, None])
     leader = jnp.argmax(same, axis=1)       # first live lane sharing my dst
     is_leader = ok & (leader == arange)

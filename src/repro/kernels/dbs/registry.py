@@ -32,10 +32,10 @@ from __future__ import annotations
 from dataclasses import dataclass
 from typing import Callable, Dict, Optional, Tuple
 
-import jax
 import jax.numpy as jnp
 
 from repro.kernels.dbs import ops as _ops
+from repro.kernels.platform import default_interpret
 
 
 @dataclass(frozen=True)
@@ -97,7 +97,8 @@ def resolve_kernel_name(cfg) -> str:
     """``EngineConfig`` -> registry name, honouring the legacy ``cow`` axis:
     an explicit ``kernel`` wins; ``kernel="auto"`` follows ``cow``
     (``"pallas"``/``"ref"`` keep their historical meaning, ``"auto"`` picks
-    the Pallas path on TPU and the XLA reference elsewhere)."""
+    the XLA reference where kernels would run interpreted — the CPU — and
+    the compiled Pallas path everywhere else)."""
     kernel = getattr(cfg, "kernel", "auto")
     if kernel != "auto":
         return kernel
@@ -106,7 +107,7 @@ def resolve_kernel_name(cfg) -> str:
         return "pallas"
     if cow == "ref":
         return "xla"
-    return "pallas" if jax.default_backend() == "tpu" else "xla"
+    return "xla" if default_interpret() else "pallas"
 
 
 # ---------------------------------------------------------------------------
@@ -125,12 +126,14 @@ def _xla_read(pool, ext, block_offsets):
 
 def _copy_write(pool, ops, payload, block_offsets):
     # the PR-3 hybrid: Pallas CoW copy, then the XLA block scatter.
-    # write_pages guarantees cow_src>=0 implies ok, but gate on ok anyway so
-    # a hostile ops batch can never route a copy through a clamped dst.
+    # write_pages guarantees cow_src>=0 implies a live lane, but gate on it
+    # anyway so a hostile ops batch can never route a copy through a
+    # clamped dst.
+    live = ops.live()
     pool = _ops.dbs_copy_pool(pool, ops.cow_src, ops.dst,
-                              (ops.cow_src >= 0) & ops.ok, scratch=True)
-    # not-ok lanes scatter out of bounds and are dropped (write_pages note)
-    drop_dst = jnp.where(ops.ok, jnp.maximum(ops.dst, 0), pool.shape[0])
+                              (ops.cow_src >= 0) & live, scratch=True)
+    # dead lanes scatter out of bounds and are dropped (write_pages note)
+    drop_dst = jnp.where(live, jnp.maximum(ops.dst, 0), pool.shape[0])
     return pool.at[drop_dst, block_offsets].set(payload, mode="drop")
 
 

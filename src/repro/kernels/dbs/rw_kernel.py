@@ -14,14 +14,17 @@ real extent row — a leader composes its destination row per block from
 either a member lane's payload (``lane_of``) or the source row (the CoW
 source when copying, the destination itself when writing in place) and
 writes the row ONCE. Routing every non-leader/masked lane to a reserved
-dump row is what makes the kernel safe under the interpret-mode staleness
-rule (docs/KERNELS.md): no two grid steps ever write the same live row, and
-no step reads a row another step wrote.
+dump row is what makes the kernel agree between interpret mode (each step
+reads the original buffer) and compiled execution (each step reads HBM as
+earlier steps left it): no two grid steps ever write the same live row,
+and no step reads a row another step wrote — CoW sources are never
+destinations of the same batch (the ``dbs.WriteOps`` contract).
 
-Read grid: one step per read lane; the index map DMAs exactly the (1, 1, D)
-block named by the clamped extent id, and the kernel masks holes
-(``ext < 0``) to zeros in VMEM using the RAW extent id, which rides along
-as a second scalar-prefetch operand.
+Read grid: one step per read lane; the index map fetches the aligned tile
+of 8 blocks (``read_tile_rows``) holding the lane's block from the clamped
+extent id — the TPU tiles the pool's (page, D) dims in (8, 128), so a
+single-block fetch is not a legal DMA — and the kernel selects the block
+in VMEM, masking holes (``ext < 0``) to zeros with the RAW extent id.
 """
 from __future__ import annotations
 
@@ -33,13 +36,24 @@ from jax.experimental.pallas import tpu as pltpu
 
 def _write_kernel(src_ref, dst_ref, lane_ref, src_row, payload, o_ref):
     i = pl.program_id(0)
-    lanes = lane_ref[i]                    # (page,) writing lane, -1 = keep
-    take = lanes >= 0
-    rows = payload[jnp.maximum(lanes, 0)]  # (page, D)
-    o_ref[...] = jnp.where(take[None, :, None], rows[None], src_row[...])
+    page = o_ref.shape[1]
+    o_ref[...] = src_row[...]
+
+    # SMEM yields one scalar per load, so the block -> lane map is walked
+    # block by block; each taken block is one (1, D) row copy out of the
+    # VMEM-resident payload
+    def put(j, carry):
+        lane = lane_ref[i * page + j]
+
+        @pl.when(lane >= 0)
+        def _():
+            o_ref[0, pl.ds(j, 1), :] = payload[pl.ds(lane, 1), :]
+        return carry
+
+    jax.lax.fori_loop(0, page, put, 0)
 
 
-def dbs_rw_write(pool, src, dst, lane_of, payload, *, interpret=True):
+def dbs_rw_write(pool, src, dst, lane_of, payload, *, interpret):
     """pool: (E, page, D); src/dst: (B,) int32 extent ids; lane_of: (B, page)
     int32 block -> payload lane (-1 keeps the source block); payload: (B, D).
 
@@ -52,7 +66,7 @@ def dbs_rw_write(pool, src, dst, lane_of, payload, *, interpret=True):
     return pl.pallas_call(
         _write_kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=3,          # src, dst, lane_of
+            num_scalar_prefetch=3,          # src, dst, flat lane_of
             grid=(b,),
             in_specs=[
                 pl.BlockSpec((1, page, d),
@@ -67,34 +81,44 @@ def dbs_rw_write(pool, src, dst, lane_of, payload, *, interpret=True):
         out_shape=jax.ShapeDtypeStruct(pool.shape, pool.dtype),
         input_output_aliases={3: 0},        # pool (first tensor arg) -> out
         interpret=interpret,
-    )(src, dst, lane_of, pool, payload)
+    )(src, dst, lane_of.reshape(-1), pool, payload)
 
 
-def _read_kernel(ext_ref, extc_ref, blk_ref, blk, o_ref):
+def _read_kernel(ext_ref, blk_ref, tile, o_ref):
     i = pl.program_id(0)
-    o_ref[...] = jnp.where(ext_ref[i] >= 0, blk[...], 0)
+    row = blk_ref[i] % tile.shape[1]
+    got = tile[0, pl.ds(row, 1), :]
+    o_ref[0] = jnp.where(ext_ref[i] >= 0, got, 0)
 
 
-def dbs_rw_read(pool, ext, block, *, interpret=True):
+def read_tile_rows(page: int) -> int:
+    """Blocks per read-kernel DMA: the TPU tiles an (E, page, D) pool's
+    (page, D) dims in (8, 128) tiles, so the smallest legal row slice is
+    8 blocks (the whole page when page is not a multiple of 8)."""
+    return 8 if page % 8 == 0 else page
+
+
+def dbs_rw_read(pool, ext, block, *, interpret):
     """pool: (E, page, D); ext: (B,) int32, -1 = hole (reads as zeros);
     block: (B,) int32 block offset within the page. Returns (B, D)."""
     e, page, d = pool.shape
     b = ext.shape[0]
-    extc = jnp.clip(ext, 0, e - 1)          # clamped id drives the DMA...
-    blkc = jnp.clip(block, 0, page - 1)
-    out = pl.pallas_call(
+    rows = read_tile_rows(page)
+    return pl.pallas_call(
         _read_kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=3,          # ext (raw), ext (clamped), block
+            num_scalar_prefetch=2,          # ext (raw), block
             grid=(b,),
-            in_specs=[
-                pl.BlockSpec((1, 1, d),
-                             lambda i, e_, ec, bk: (ec[i], bk[i], 0)),
-            ],
-            out_specs=pl.BlockSpec((1, 1, d),
-                                   lambda i, e_, ec, bk: (i, 0, 0)),
+            # the aligned tile of `rows` blocks holding the lane's block,
+            # from the clamped extent id (the raw id masks the hole)
+            in_specs=[pl.BlockSpec(
+                (1, rows, d),
+                lambda i, ex, bk: (jnp.clip(ex[i], 0, e - 1),
+                                   bk[i] // rows, 0))],
+            # one (1, D) output block per lane, streamed back as the grid
+            # runs: its last two dims are the (B, 1, D) array's own
+            out_specs=pl.BlockSpec((1, 1, d), lambda i, ex, bk: (i, 0, 0)),
         ),
         out_shape=jax.ShapeDtypeStruct((b, 1, d), pool.dtype),
         interpret=interpret,
-    )(ext, extc, blkc, pool)                # ...the raw id masks the hole
-    return out[:, 0, :]
+    )(ext, jnp.clip(block, 0, page - 1), pool).reshape(b, d)

@@ -1,4 +1,3 @@
 """Deprecation shim: the ops surface lives in ``repro.kernels.dbs.ops``."""
-from repro.kernels.dbs.ops import (_use_interpret, dbs_copy,  # noqa: F401
-                                   dbs_copy_pool, dbs_copy_reference,
-                                   default_interpret)
+from repro.kernels.dbs.ops import (dbs_copy, dbs_copy_pool,  # noqa: F401
+                                   dbs_copy_reference, default_interpret)

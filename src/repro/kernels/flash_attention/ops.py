@@ -8,10 +8,7 @@ import jax.numpy as jnp
 
 from repro.kernels.flash_attention.kernel import flash_attention_fwd
 from repro.kernels.flash_attention.ref import attention_ref
-
-
-def _use_interpret():
-    return jax.default_backend() != "tpu"
+from repro.kernels.platform import default_interpret
 
 
 @partial(jax.jit, static_argnames=("window", "logit_cap", "scale",
@@ -30,7 +27,7 @@ def flash_attention(q, k, v, q_pos=None, k_pos=None, *, window=0,
     out = flash_attention_fwd(qt, kt, vt, causal=True, window=window,
                               logit_cap=logit_cap, scale=scale,
                               block_q=block_q, block_k=block_k,
-                              interpret=_use_interpret())
+                              interpret=default_interpret())
     return out.swapaxes(1, 2).astype(q.dtype)
 
 

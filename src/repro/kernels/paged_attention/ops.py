@@ -10,10 +10,7 @@ from repro.kernels.paged_attention.kernel import (paged_attention_fwd,
                                                   paged_attention_pool_fwd)
 from repro.kernels.paged_attention.ref import (paged_attention_pool_ref,
                                                paged_attention_ref)
-
-
-def _use_interpret():
-    return jax.default_backend() != "tpu"
+from repro.kernels.platform import default_interpret
 
 
 @partial(jax.jit, static_argnames=("window", "logit_cap", "scale"))
@@ -24,7 +21,7 @@ def paged_attention(q, pool_k, pool_v, block_table, lengths, *, window=0,
     Returns (B,H,hd_v)."""
     return paged_attention_fwd(q, pool_k, pool_v, block_table, lengths,
                                window=window, logit_cap=logit_cap,
-                               scale=scale, interpret=_use_interpret())
+                               scale=scale, interpret=default_interpret())
 
 
 @partial(jax.jit, static_argnames=("k_plane", "v_plane", "window",
@@ -38,7 +35,7 @@ def paged_attention_pool(q, pool, block_table, lengths, *, k_plane, v_plane,
     return paged_attention_pool_fwd(q, pool, block_table, lengths,
                                     k_plane=k_plane, v_plane=v_plane,
                                     window=window, logit_cap=logit_cap,
-                                    scale=scale, interpret=_use_interpret())
+                                    scale=scale, interpret=default_interpret())
 
 
 paged_attention_reference = paged_attention_ref

@@ -30,15 +30,16 @@ from repro.utils import hlo as hlo_utils
 
 from repro.utils.machine import machine_profile
 
-# machine peaks: detected-or-overridable (utils/machine.py); the v5e
-# assignment-brief numbers remain the fallback
+# the dry run stands 512 placeholder CPU devices in for v5e pods
+# (launch/mesh.py), so its roofline terms use the v5e peaks
+# (utils/machine.py; the REPRO_* variables still override)
 _PROFILE = None
 
 
 def _peaks():
     global _PROFILE
     if _PROFILE is None:
-        _PROFILE = machine_profile()
+        _PROFILE = machine_profile(device_kind="TPU v5 lite")
     return _PROFILE
 
 

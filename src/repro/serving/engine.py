@@ -47,6 +47,7 @@ from repro.core.frontend import MultiQueueFrontend, Request
 from repro.core.ring import OP_CLONE, ST_OK
 from repro.kernels.paged_attention.kernel import paged_attention_pool_fwd
 from repro.kernels.paged_attention.ref import paged_attention_pool_ref
+from repro.kernels.platform import default_interpret
 from repro.models import blocks as B
 from repro.models import model as M
 
@@ -138,7 +139,7 @@ class ServeEngine:
             self._pools = self.volumes.device_pools()
             self._table = self.volumes.device_extent_map()
             self._attn_pallas = (kernel == "pallas" or (
-                kernel == "auto" and jax.default_backend() == "tpu"))
+                kernel == "auto" and not default_interpret()))
             self._cow_pending: set = set()
             self._step_fn = jax.jit(self._decode_program)
         else:
@@ -325,7 +326,7 @@ class ServeEngine:
                     qk, cell["pools"][0], bt_, lengths, k_plane=kp,
                     v_plane=vp, window=window, logit_cap=logit_cap,
                     scale=eff_scale,
-                    interpret=jax.default_backend() != "tpu")
+                    interpret=default_interpret())
             else:
                 out = paged_attention_pool_ref(
                     qk, cell["pools"][0], bt_, lengths, k_plane=kp,

@@ -1,13 +1,12 @@
-"""Machine roofline profile: detected-or-overridable peak numbers.
+"""Machine roofline profile: the published peaks of the device in use.
 
-The dry-run/roofline analysis used to hardcode one TPU generation's peaks,
-so bytes/s-vs-peak fractions were silently wrong on any other box. One
-``machine_profile()`` now feeds every consumer (``launch/dryrun.py``,
-``benchmarks/roofline.py``, the ladder's kernel gate), resolved in priority
-order: explicit values (CLI flags) > ``REPRO_PEAK_FLOPS`` /
-``REPRO_HBM_BW`` / ``REPRO_LINK_BW`` env vars > the jax device kind >
-the v5e assignment-brief defaults (flagged ``assumed=True`` so reports can
-say so).
+One ``machine_profile()`` feeds every consumer (``launch/dryrun.py``,
+``benchmarks/roofline.py``, the ladder's kernel table). Peaks come from the
+table below, keyed by the jax ``device_kind``. A device that is not in the
+table is an error unless every peak is given explicitly — as arguments or
+as the ``REPRO_PEAK_FLOPS`` / ``REPRO_HBM_BW`` / ``REPRO_LINK_BW``
+environment variables, which also override single peaks of a known device.
+No device is ever handed another device's peaks.
 """
 from __future__ import annotations
 
@@ -21,38 +20,23 @@ class MachineProfile:
     name: str
     peak_flops: float       # peak matmul flops/s per chip (bf16)
     hbm_bw: float           # HBM bytes/s per chip
-    link_bw: float          # ICI bytes/s per link
-    assumed: bool = False   # True when nothing was detected or overridden
+    link_bw: float          # chip-to-chip interconnect bytes/s per chip
 
     def to_dict(self) -> dict:
         return asdict(self)
 
 
-# the assignment brief's v5e numbers — the old hardcoded constants
-V5E = MachineProfile("tpu-v5e", 197e12, 819e9, 50e9)
+# Published per-chip peaks (Google Cloud documentation, "TPU v5e"): bf16
+# matmul, HBM bandwidth, and interchip interconnect — 1,600 Gbit/s, i.e.
+# 200e9 B/s. The table holds only devices whose figures were checked
+# against that source; any other device names its peaks explicitly.
+V5E = MachineProfile("tpu-v5e", 197e12, 819e9, 200e9)
 
-# device_kind (prefix-matched, case-insensitive) -> published peaks
+# jax device_kind (lower-cased, exact) -> published peaks
 _KNOWN = {
     "tpu v5 lite": V5E,
     "tpu v5e": V5E,
-    "tpu v5p": MachineProfile("tpu-v5p", 459e12, 2765e9, 100e9),
-    "tpu v5": MachineProfile("tpu-v5p", 459e12, 2765e9, 100e9),
-    "tpu v4": MachineProfile("tpu-v4", 275e12, 1228e9, 50e9),
-    "tpu v6 lite": MachineProfile("tpu-v6e", 918e12, 1640e9, 100e9),
-    "tpu v6e": MachineProfile("tpu-v6e", 918e12, 1640e9, 100e9),
 }
-
-
-def _detect() -> Optional[MachineProfile]:
-    try:
-        import jax
-        kind = jax.devices()[0].device_kind.lower()
-    except Exception:
-        return None
-    for prefix, prof in _KNOWN.items():
-        if kind.startswith(prefix):
-            return prof
-    return None
 
 
 def _env(name: str) -> Optional[float]:
@@ -62,21 +46,34 @@ def _env(name: str) -> Optional[float]:
 
 def machine_profile(peak_flops: Optional[float] = None,
                     hbm_bw: Optional[float] = None,
-                    link_bw: Optional[float] = None) -> MachineProfile:
-    """Resolve the machine's roofline peaks (module docstring priority)."""
+                    link_bw: Optional[float] = None, *,
+                    device_kind: Optional[str] = None) -> MachineProfile:
+    """Resolve the roofline peaks of ``device_kind`` (default: the kind of
+    ``jax.devices()[0]``). Explicit arguments win over the ``REPRO_*``
+    environment variables, which win over the table. Raises ``ValueError``
+    for a kind the table does not hold unless all three peaks are given."""
     peak_flops = peak_flops if peak_flops is not None else \
         _env("REPRO_PEAK_FLOPS")
     hbm_bw = hbm_bw if hbm_bw is not None else _env("REPRO_HBM_BW")
     link_bw = link_bw if link_bw is not None else _env("REPRO_LINK_BW")
-    base = _detect()
-    assumed = base is None and not (peak_flops and hbm_bw and link_bw)
-    base = base or V5E
-    name = base.name if base is not V5E or not assumed else "tpu-v5e-assumed"
-    if peak_flops or hbm_bw or link_bw:
+    if device_kind is None:
+        import jax
+        device_kind = jax.devices()[0].device_kind
+    base = _KNOWN.get(device_kind.lower())
+    explicit = (peak_flops, hbm_bw, link_bw)
+    if base is None:
+        if any(v is None for v in explicit):
+            raise ValueError(
+                f"no published peaks for device_kind {device_kind!r} "
+                f"(known: {', '.join(sorted(_KNOWN))}); give peak_flops, "
+                "hbm_bw and link_bw explicitly or set REPRO_PEAK_FLOPS, "
+                "REPRO_HBM_BW and REPRO_LINK_BW")
+        return MachineProfile(device_kind, *explicit)
+    name = base.name
+    if any(v is not None for v in explicit):
         name += "+overrides"
     return MachineProfile(
         name=name,
         peak_flops=peak_flops if peak_flops is not None else base.peak_flops,
         hbm_bw=hbm_bw if hbm_bw is not None else base.hbm_bw,
-        link_bw=link_bw if link_bw is not None else base.link_bw,
-        assumed=assumed)
+        link_bw=link_bw if link_bw is not None else base.link_bw)

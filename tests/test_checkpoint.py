@@ -5,7 +5,6 @@ import os
 import jax
 import jax.numpy as jnp
 import numpy as np
-import pytest
 
 from repro.checkpoint import CheckpointStore, ReplicatedCheckpoint
 from repro.core.dbs_host import DBSHost
@@ -87,8 +86,6 @@ def test_replicated_write_all_fail_rebuild(tmp_path):
     rc.close()
 
 
-@pytest.mark.skipif(not hasattr(jax.sharding, "AxisType"),
-                    reason="jax.sharding.AxisType not available in this jax")
 def test_elastic_restore_resharding(tmp_path):
     """Restore onto a different (1-device) mesh sharding — the elastic path."""
     from jax.sharding import NamedSharding, PartitionSpec as P

@@ -189,3 +189,72 @@ def test_snapshot_count_independent_reads():
         assert int(sid) >= 0
         ext = np.asarray(jax.device_get(dbs.read_resolve(st_, v, jnp.arange(4))))
         np.testing.assert_array_equal(ext, first)  # same one-gather lookup
+
+
+# ---------------------------------------------------------------------------
+# the WriteOps contract the streamed write kernel relies on
+# ---------------------------------------------------------------------------
+_LANES = 6
+_jit = {name: jax.jit(getattr(dbs, name)) for name in (
+    "create_volume", "snapshot", "clone", "unmap", "delete_volume",
+    "write_pages")}
+_lane = st.tuples(st.integers(0, MAX_VOLS - 1), st.integers(0, MAX_PAGES - 1),
+                  st.booleans())
+_contract_op = st.one_of(
+    st.tuples(st.just("write_pages"),
+              st.lists(_lane, min_size=_LANES, max_size=_LANES)),
+    st.tuples(st.sampled_from(["snapshot", "clone", "delete_volume"]),
+              st.integers(0, MAX_VOLS - 1)),
+    st.tuples(st.just("unmap"), st.integers(0, MAX_VOLS - 1),
+              st.integers(0, MAX_PAGES - 1)),
+    st.tuples(st.just("create_volume")),
+)
+
+
+def _write_batch(s, lanes):
+    """One multi-volume ``write_pages`` batch (lanes: (vol, page, mask);
+    lanes of unused volumes are masked off), checked against the contract:
+    its live destinations and its CoW sources are disjoint."""
+    vol, page, mask = (np.asarray(c) for c in zip(*lanes))
+    live_vol = np.asarray(s.vol_head)[vol] >= 0
+    s, w = _jit["write_pages"](s, jnp.asarray(vol, jnp.int32),
+                               jnp.asarray(page, jnp.int32),
+                               jnp.ones((len(lanes),), jnp.uint32),
+                               jnp.asarray(mask & live_vol))
+    live = np.asarray(w.dst)[np.asarray(w.live())]
+    src = np.asarray(w.cow_src)
+    assert set(live.tolist()).isdisjoint(src[src >= 0].tolist()), lanes
+    return s, int(np.sum(src >= 0))
+
+
+@settings(max_examples=30, deadline=None,
+          suppress_health_check=list(HealthCheck))
+@given(st.lists(_contract_op, min_size=1, max_size=16))
+def test_write_pages_cow_sources_are_never_batch_destinations(ops):
+    """Over random sequences of snapshot, clone, unmap, delete and
+    multi-volume write batches, no ``write_pages`` batch names an extent
+    both as a live destination and as a CoW source (the ``dbs.WriteOps``
+    convention the compiled ``dbs_rw_write`` kernel depends on: it streams
+    rows, so such a source would read back bytes the batch just wrote)."""
+    s = dbs.make_state(64, MAX_VOLS, MAX_PAGES)
+    s, _ = _jit["create_volume"](s)
+    s, _ = _jit["create_volume"](s)
+    for v in (0, 1):
+        s, _ = _write_batch(s, [(v, p, True) for p in range(MAX_PAGES)])
+    s, _ = _jit["clone"](s, jnp.int32(0))
+    # the source and its clone (volume 2) write the pages they share, next
+    # to in-place writes of volume 1, in one batch: two CoW groups per page
+    s, n_cow = _write_batch(s, [(v, p, True) for p in (0, 1)
+                                for v in range(3)])
+    assert n_cow == 4
+    for op in ops:
+        name, args = op[0], op[1:]
+        if name == "write_pages":
+            s, _ = _write_batch(s, args[0])
+        elif name == "unmap":
+            s = _jit["unmap"](s, jnp.int32(args[0]),
+                              jnp.asarray([args[1]], jnp.int32))
+        elif name == "delete_volume":
+            s = _jit["delete_volume"](s, jnp.int32(args[0]))
+        else:
+            s, _ = _jit[name](s, *(jnp.int32(a) for a in args))

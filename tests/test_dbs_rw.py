@@ -27,6 +27,7 @@ from repro.kernels.dbs import (available_kernels, dbs_rw_read_pool,
                                dbs_rw_write_pool, make_kernel,
                                register_kernel, resolve_kernel_name)
 from repro.kernels.dbs.registry import _REGISTRY, DBSKernel
+from repro.kernels.platform import default_interpret
 
 KEY = jax.random.PRNGKey(0)
 
@@ -89,6 +90,10 @@ def test_write_matches_xla_on_write_pages_ops(kernel):
     mask = jnp.arange(8) % 2 == 0               # masked lanes ride along
     st, ops = dbs.write_pages(st, vol, pages, bits, mask)
     assert bool(jnp.any(ops.cow_src >= 0)), "expected CoW lanes"
+    # the WriteOps contract the kernels rely on: no CoW source is a live
+    # destination of the same batch
+    live_dst = set(np.asarray(ops.dst)[np.asarray(ops.live())].tolist())
+    assert live_dst.isdisjoint(np.asarray(ops.cow_src).tolist())
     payload2 = jax.random.normal(jax.random.PRNGKey(3), (8, 4))
     ref = make_kernel("xla").write(pool, ops, payload2, blocks)
     out = make_kernel(kernel).write(pool, ops, payload2, blocks)
@@ -164,6 +169,7 @@ def test_write_read_property():
     from hypothesis import strategies as st_
 
     E, PAGE, D, B = 12, 4, 8, 10
+    compiled = not default_interpret()
 
     @settings(max_examples=25, deadline=None)
     @given(data=st_.data())
@@ -184,16 +190,26 @@ def test_write_read_property():
         same = live[None, :] & live[:, None] & (dst[None, :] == dst[:, None])
         leader = jnp.argmax(same, axis=1)
         is_leader = live & (leader == jnp.arange(B))
-        ops = dbs.WriteOps(dst=dst, cow_src=jnp.where(is_leader, cow, -1),
-                           ok=ok)
+        cow = jnp.where(is_leader, cow, -1)
+        ops = dbs.WriteOps(dst=dst, cow_src=cow, ok=ok)
+        # compiled, the Pallas kernels (pallas, and copy's CoW half)
+        # stream rows, so they alone are held to the second WriteOps
+        # convention too: no CoW source is a live destination of the batch
+        # (write_pages honours it, see test_dbs_properties); everything
+        # else takes the full draw
+        cow_is_dst = jnp.any(live[None, :] & (dst[None, :] == cow[:, None]),
+                             axis=1)
+        streamed = dbs.WriteOps(dst=dst, cow_src=jnp.where(cow_is_dst, -1,
+                                                           cow), ok=ok)
         pool = jax.random.normal(KEY, (E, PAGE, D))
         payload = jax.random.normal(jax.random.PRNGKey(1), (B, D))
-        ref = make_kernel("xla").write(pool, ops, payload, blocks)
-        for name in ("pallas", "ref"):
-            out = make_kernel(name).write(pool, ops, payload, blocks)
+        for name in ("pallas", "ref", "copy"):
+            o = streamed if name != "ref" and compiled else ops
+            ref = make_kernel("xla").write(pool, o, payload, blocks)
+            out = make_kernel(name).write(pool, o, payload, blocks)
             _assert_rows_equal(out, ref)
         rref = make_kernel("xla").read(pool, ext, blocks)
-        for name in ("pallas", "ref"):
+        for name in ("pallas", "ref", "copy"):
             got = make_kernel(name).read(pool, ext, blocks)
             np.testing.assert_array_equal(np.asarray(got), np.asarray(rref))
 

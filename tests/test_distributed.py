@@ -12,18 +12,6 @@ import pytest
 
 SRC = os.path.join(os.path.dirname(__file__), "..", "src")
 
-# feature gates: these tests exercise jax APIs newer than some pinned
-# environments (e.g. jax 0.4.37 has neither jax.sharding.AxisType nor
-# top-level jax.shard_map) — skip rather than fail there
-needs_axis_type = pytest.mark.skipif(
-    not hasattr(jax.sharding, "AxisType"),
-    reason="jax.sharding.AxisType not available in this jax")
-needs_shard_map = pytest.mark.skipif(
-    not hasattr(jax, "shard_map"),
-    reason="top-level jax.shard_map not available in this jax")
-
-
-@needs_axis_type
 def test_planner_divisibility_fallbacks():
     import jax
     from jax.sharding import PartitionSpec as P
@@ -57,7 +45,6 @@ def test_planner_divisibility_fallbacks():
     assert tuple(spec) == ("model", None, None)
 
 
-@needs_axis_type
 def test_all_param_leaves_get_specs():
     import jax
     from repro.configs import ALL_ARCHS, ExecutionPlan, get_config, smoke_config
@@ -80,8 +67,6 @@ def test_all_param_leaves_get_specs():
         assert n_specs == n_leaves, arch
 
 
-@needs_axis_type
-@needs_shard_map
 @pytest.mark.slow
 @pytest.mark.parametrize("arch,shape", [
     ("gemma2-2b", "train_4k"),

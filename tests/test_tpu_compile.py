@@ -1,0 +1,103 @@
+"""Compile rehearsals for one TPU v5e chip, with no chip attached.
+
+The main path's Pallas kernels are compiled (not run) for one device of a
+described ``v5e:2x2`` topology at deployment widths. Interpret mode cannot
+tell whether the chip's compiler accepts a block shape or an SMEM load;
+these compiles can. The topology is described inside a fixture, never at
+import: only one process at a time may load the TPU compiler's library, and
+every test worker imports this file.
+"""
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from repro.core import dbs
+from repro.kernels.dbs import (dbs_copy_pool, dbs_rw_read_pool,
+                               dbs_rw_write_pool)
+from repro.kernels.paged_attention.kernel import paged_attention_pool_fwd
+
+# the chip_smoke.py geometry: 4 KiB blocks as float32 lanes, 32-block
+# extents, 4096 extents plus the reserved dump row, 64-lane batches
+E, PAGE, D, B = 4096 + 1, 32, 4096, 64
+POOL_BYTES = E * PAGE * D * 4
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    from jax.experimental import topologies
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setenv("TPU_LOG_DIR", "disabled")    # no compiler logs under /tmp
+        try:
+            topo = topologies.get_topology_desc(platform="tpu",
+                                                topology_name="v5e:2x2")
+        except Exception as e:  # noqa: BLE001 — any failure: no topology
+            pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+        yield SingleDeviceSharding(topo.devices[0])
+
+
+def _shape(sharding, shape, dtype=jnp.float32):
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+
+def _write_ops(sharding):
+    i32 = lambda: _shape(sharding, (B,), jnp.int32)     # noqa: E731
+    return dbs.WriteOps(dst=i32(), cow_src=i32(),
+                        ok=_shape(sharding, (B,), jnp.bool_))
+
+
+def _compile(fn, *args, donate=()):
+    compiled = jax.jit(fn, donate_argnums=donate).lower(*args).compile()
+    assert "tpu_custom_call" in compiled.as_text()      # the kernel is there
+    return compiled
+
+
+def test_dbs_rw_write_compiles_in_place(one_chip):
+    """The whole write plane (routing + kernel) at the smoke geometry, with
+    the pool donated as the engine step donates it: no pool-sized copy."""
+    c = _compile(
+        lambda pool, ops, pay, blk: dbs_rw_write_pool(pool, ops, pay, blk,
+                                                      interpret=False),
+        _shape(one_chip, (E, PAGE, D)), _write_ops(one_chip),
+        _shape(one_chip, (B, D)), _shape(one_chip, (B,), jnp.int32),
+        donate=(0,))
+    mem = c.memory_analysis()
+    assert mem.alias_size_in_bytes >= POOL_BYTES
+    assert mem.temp_size_in_bytes < POOL_BYTES // 64
+
+
+def test_dbs_rw_read_compiles(one_chip):
+    c = _compile(
+        lambda pool, ext, blk: dbs_rw_read_pool(pool, ext, blk,
+                                                interpret=False),
+        _shape(one_chip, (E, PAGE, D)), _shape(one_chip, (B,), jnp.int32),
+        _shape(one_chip, (B,), jnp.int32))
+    mem = c.memory_analysis()
+    assert mem.output_size_in_bytes == B * D * 4
+    assert mem.temp_size_in_bytes < POOL_BYTES // 64
+
+
+def test_dbs_copy_compiles(one_chip):
+    _compile(
+        lambda pool, src, dst, m: dbs_copy_pool(pool, src, dst, m,
+                                                interpret=False,
+                                                scratch=True),
+        _shape(one_chip, (E, PAGE, D)), _shape(one_chip, (B,), jnp.int32),
+        _shape(one_chip, (B,), jnp.int32),
+        _shape(one_chip, (B,), jnp.bool_), donate=(0,))
+
+
+def test_paged_attention_pool_compiles_granite_widths(one_chip):
+    """Zero-copy decode attention over one engine pool at granite-3-8b
+    widths: 40 layers -> 80 K/V planes, 8 KV heads, head_dim 128, 32 query
+    heads; 16-block pages, 32 pages (512 tokens) per sequence."""
+    layers, kv, hd, heads = 40, 8, 128, 32
+    page, n_pages, seqs, ext = 16, 32, 8, 257
+    _compile(
+        lambda q, pool, tbl, ln: paged_attention_pool_fwd(
+            q, pool, tbl, ln, k_plane=2 * (layers - 1),
+            v_plane=2 * layers - 1, interpret=False),
+        _shape(one_chip, (seqs, heads, hd)),
+        _shape(one_chip, (ext, page, 2 * layers, kv, hd)),
+        _shape(one_chip, (seqs, n_pages), jnp.int32),
+        _shape(one_chip, (seqs,), jnp.int32))

@@ -152,9 +152,6 @@ def test_optimizers_descend_quadratic():
                                    np.asarray(jnp.eye(4)), atol=0.15)
 
 
-@pytest.mark.skipif(not hasattr(jax, "shard_map"),
-                    reason="repro.distributed.collectives needs top-level "
-                           "jax.shard_map, unavailable in this jax")
 def test_gradient_compression_roundtrip():
     from repro.distributed.collectives import compress_int8, decompress_int8
     x = jax.random.normal(KEY, (128,)) * 3.0
