@@ -1,0 +1,3 @@
+"""``pump_deliver_ms`` read in the bandwidth cell, where it moves
+``bandwidth_mib_s`` and not ``iops``: the same reader."""
+from bench.metrics.pump_deliver_ms import read  # noqa: F401

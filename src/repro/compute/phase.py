@@ -34,7 +34,7 @@ import jax.numpy as jnp
 
 from repro.compute import registry as sfns
 from repro.core import dbs
-from repro.core.fused import _cow_apply
+from repro.core.fused import _cow_apply, _cow_work
 from repro.core.transport import stamp_page_rev
 
 
@@ -53,7 +53,8 @@ def apply_compute_ops(states, pools, page_revs, healthy, batch, mask,
                       value, status, reads, *, kernel: str, tail: int):
     """Apply the batch's compute lanes in lane order. ``mask`` is
     ``ok & (op == OP_COMPUTE)``. Returns updated
-    ``(states, pools, page_revs, value, status, reads)``."""
+    ``(states, pools, page_revs, value, status, reads)`` and the int32
+    ``(rows, calls)`` the CAS commit's write kernel adds to ``CQ.work``."""
     table = sfns.device_table()
     n_fns = len(table)
     b_n = batch.op.shape[0]
@@ -109,6 +110,7 @@ def apply_compute_ops(states, pools, page_revs, healthy, batch, mask,
     reads = jax.lax.dynamic_update_slice_in_dim(
         reads, jnp.where(live_b, outs, sl(reads)), start, axis=0)
 
+    work = jnp.zeros((2,), jnp.int32)
     if any(e.writes for e in table):
         # single CAS commit (at most one do_write lane per batch): scatter
         # the window's one-hot write mask back to batch shape and run the
@@ -121,6 +123,7 @@ def apply_compute_ops(states, pools, page_revs, healthy, batch, mask,
         for i, st in enumerate(states):
             st, wops = dbs.write_pages(st, batch.volume, batch.page, bits,
                                        wmask & healthy[i])
+            work = work + _cow_work(pools[i], wops, batch.block, kernel)
             out_pools.append(_cow_apply(pools[i], wops, batch.payload,
                                         batch.block, kernel))
             out_prs.append(stamp_page_rev(page_revs[i], batch.volume,
@@ -129,4 +132,4 @@ def apply_compute_ops(states, pools, page_revs, healthy, batch, mask,
         states, pools, page_revs = (tuple(out_states), tuple(out_pools),
                                     tuple(out_prs))
 
-    return states, pools, page_revs, value, status, reads
+    return states, pools, page_revs, value, status, reads, work

@@ -63,11 +63,13 @@ in-flight I/O (including write-behind replica traffic) on exit, and
 """
 from __future__ import annotations
 
+import gc
 import itertools
 from dataclasses import dataclass, field
 from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.core.engine import Engine, EngineConfig
 from repro.core.frontend import Request
@@ -78,6 +80,22 @@ from repro.core.transport import (MSG_CLONE, MSG_CREATE, MSG_DELETE,
 # control kinds the durability journal records (core -> journal opcode)
 _JOURNAL_CTRL = {"snapshot": MSG_SNAPSHOT, "clone": MSG_CLONE,
                  "delete": MSG_DELETE}
+
+# the open ``py.gc`` span: ``gc.callbacks`` is process-wide, and Python
+# runs one collection at a time
+_gc_spans: List[TraceAnnotation] = []
+
+
+def _gc_span(phase: str, info: Dict[str, int]) -> None:
+    """``gc.callbacks`` hook: a ``py.gc`` profiler span around each Python
+    garbage collection (``generation=`` its generation), so a pause in a
+    trace can be told from the engine's own work."""
+    if phase == "start":
+        span = TraceAnnotation("py.gc", generation=info["generation"])
+        span.__enter__()
+        _gc_spans.append(span)
+    elif _gc_spans:
+        _gc_spans.pop().__exit__(None, None, None)
 
 
 def _bytes_to_lanes(data: bytes) -> np.ndarray:
@@ -329,6 +347,11 @@ class VolumeManager:
         self._pending_w: Dict[int, set] = {}
         self._pending_r: Dict[int, set] = {}
         self._n_pending = 0
+        # hazard-fence flushes and the engine steps they dispatched
+        self._fence_flushes = 0
+        self._fence_steps = 0
+        if _gc_span not in gc.callbacks:
+            gc.callbacks.append(_gc_span)
 
     # ------------------------------------------------------------ plumbing
     def _rid(self, vid: int) -> int:
@@ -356,7 +379,12 @@ class VolumeManager:
         span = range(lo, hi)
         if ((pw and not pw.isdisjoint(span))
                 or (pr and not pr.isdisjoint(span))):
-            self.flush()
+            impl = self.engine.impl
+            steps = getattr(impl, "dispatches", 0)
+            with TraceAnnotation("vm.fence"):
+                self.flush()
+            self._fence_flushes += 1
+            self._fence_steps += getattr(impl, "dispatches", 0) - steps
 
     def _track(self, table: Dict[int, set], vid: int, lo: int,
                hi: int) -> None:
@@ -457,9 +485,34 @@ class VolumeManager:
             raise ValueError("I/O on a closed VolumeManager")
 
     def stats(self) -> Dict[str, Any]:
+        """Counters for operators, all totals since the manager was built:
+
+        - ``completed``, ``queued``: requests the engine completed / holds;
+        - ``fence_flushes``: flushes the overlapping-block hazard fence
+          forced inside ``pwrite`` (a write racing an in-flight op of the
+          same block), and, on the backends that count their steps (ring,
+          sharded), ``fence_steps``: the engine steps those flushes
+          dispatched;
+        - on ``backend="ring"``, ``write_rows`` and ``write_kernel_calls``:
+          the ``dbs_rw_write`` kernel's extent-row moves and calls
+          (``RingEngine.work_counters``; reading them syncs the device), so
+          it moves ``4 * (write_rows * page_blocks + write_kernel_calls *
+          batch) * block_bytes`` HBM bytes (one float32 lane per byte);
+        - ``slots_active``, ``journal``, ``tier`` where the backend has them.
+
+        While a profiler trace runs (``jax.profiler``), the pump records
+        the ``ring.*`` spans (core/ring.py), a hazard-fence flush a
+        ``vm.fence`` span, and each Python garbage collection a ``py.gc``
+        span."""
         out = {"completed": self.engine.completed,
                "queued": self.engine.depth(),
-               "backend": self.backend_name}
+               "backend": self.backend_name,
+               "fence_flushes": self._fence_flushes}
+        impl = self.engine.impl
+        if hasattr(impl, "dispatches"):
+            out["fence_steps"] = self._fence_steps
+        if hasattr(impl, "work_counters"):
+            out.update(impl.work_counters())
         table = getattr(self.engine.frontend, "table", None)
         if table is not None:
             from repro.core import slots

@@ -45,6 +45,7 @@ import jax.numpy as jnp
 
 from repro.core import dbs, slots
 from repro.core.transport import stamp_page_rev
+from repro.kernels.dbs.ops import dbs_rw_write_rows
 from repro.kernels.dbs.registry import make_kernel
 
 
@@ -78,6 +79,16 @@ def _cow_apply(pool, ops: dbs.WriteOps, payload, block_offsets, kernel: str):
     allocator's range as the masked-lane dump, so the Pallas paths stay
     fully input/output-aliased (no concat/slice copies of the pool)."""
     return make_kernel(kernel).write(pool, ops, payload, block_offsets)
+
+
+def _cow_work(pool, ops: dbs.WriteOps, block_offsets, kernel: str):
+    """What one ``_cow_apply`` call adds to the ring's ``CQ.work`` counters:
+    int32 ``(extent rows moved, kernel calls)`` of the ``dbs_rw_write``
+    kernel, which only the ``pallas`` entry runs (zeros for the others)."""
+    if kernel != "pallas":
+        return jnp.zeros((2,), jnp.int32)
+    return jnp.stack([dbs_rw_write_rows(pool, ops, block_offsets),
+                      jnp.int32(1)])
 
 
 def step_core(table: slots.SlotTable, states: Tuple[dbs.DBSState, ...],

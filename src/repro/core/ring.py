@@ -51,12 +51,13 @@ from typing import Any, Dict, List, Optional, Sequence, Set, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.compute import registry as compute_registry
 from repro.compute.phase import apply_compute_ops
 from repro.core import dbs, slots
 from repro.core.control import ControlDispatch
-from repro.core.fused import _cow_apply, _rr_gather
+from repro.core.fused import _cow_apply, _cow_work, _rr_gather
 from repro.core.replication import ShardedReplicaGroup
 from repro.core.transport import clone_page_rev, stamp_page_rev
 
@@ -148,6 +149,9 @@ class CQ:
     value: jnp.ndarray      # (N,) int32 op result (snapshot id / clone vol)
     latency: jnp.ndarray    # (N,) int32 completion latency in pump ticks
     payload: jnp.ndarray    # (N, *payload) read payload slots
+    work: jnp.ndarray       # (2,) int32 dbs_rw_write rows moved, kernel
+                            # calls: running totals that wrap (read by
+                            # RingEngine.work_counters)
 
 
 @jax.tree_util.register_dataclass
@@ -166,7 +170,8 @@ def make_cq(n_slots: int, payload_shape: Tuple[int, ...] = ()) -> CQ:
     z = lambda: jnp.zeros((n_slots,), jnp.int32)
     return CQ(status=z(), value=z(), latency=z(),
               payload=jnp.zeros((n_slots,) + tuple(payload_shape),
-                                jnp.float32))
+                                jnp.float32),
+              work=jnp.zeros((2,), jnp.int32))
 
 
 def make_sharded_cq(n_shards: int, n_slots: int,
@@ -331,7 +336,8 @@ def ring_step_core(table: slots.SlotTable, cq: CQ,
     and a pure-data batch pays exactly the fused step's cost plus the CQE
     scatter. ``page_revs`` are the per-replica last-write watermarks
     (``transport.stamp_page_rev``), stamped with the write phase and copied
-    whole on in-band REBUILD. Returns
+    whole on in-band REBUILD. ``cq.work`` accumulates the extent rows and
+    calls of every ``dbs_rw_write`` kernel the step runs. Returns
     ``(table', cq', states', pools', page_revs', healthy', CQEView)``.
     """
     table, ids, ok = slots.transact(table, batch.want, batch.volume,
@@ -341,6 +347,7 @@ def ring_step_core(table: slots.SlotTable, cq: CQ,
     status = jnp.zeros((b_n,), jnp.int32)
     value = jnp.full((b_n,), -1, jnp.int32)
     reads = jnp.zeros_like(batch.payload)
+    work = jnp.zeros((2,), jnp.int32)
 
     if not null_backend and states:
         if "write" in classes:                   # mirrored CoW data phase
@@ -351,6 +358,8 @@ def ring_step_core(table: slots.SlotTable, cq: CQ,
                 st, wops = dbs.write_pages(st, batch.volume, batch.page,
                                            bits, wmask & healthy[i])
                 if not null_storage:
+                    work = work + _cow_work(pools[i], wops, batch.block,
+                                            kernel)
                     out_pools.append(_cow_apply(pools[i], wops,
                                                 batch.payload, batch.block,
                                                 kernel))
@@ -370,11 +379,12 @@ def ring_step_core(table: slots.SlotTable, cq: CQ,
             # in-band storage functions: between data and control (the drain
             # never mixes compute with control lanes, so this phase and the
             # control tail are mutually exclusive per batch)
-            states, pools, page_revs, value, status, reads = (
+            states, pools, page_revs, value, status, reads, cas_work = (
                 apply_compute_ops(states, pools, page_revs, healthy, batch,
                                   ok & (batch.op == OP_COMPUTE), value,
                                   status, reads, kernel=kernel,
                                   tail=compute_tail))
+            work = work + cas_work
         if "vol" in classes:                     # lane-ordered control tail
             states, page_revs, value, status = _apply_vol_ops(
                 states, page_revs, batch, ok, value, status)
@@ -388,7 +398,8 @@ def ring_step_core(table: slots.SlotTable, cq: CQ,
     cq = CQ(status=cq.status.at[idx].set(status, mode="drop"),
             value=cq.value.at[idx].set(value, mode="drop"),
             latency=cq.latency.at[idx].set(latency, mode="drop"),
-            payload=cq.payload.at[idx].set(reads, mode="drop"))
+            payload=cq.payload.at[idx].set(reads, mode="drop"),
+            work=cq.work + work)
     # mirror the status into the Messages Array's status lane
     table = dataclasses.replace(
         table, status=table.status.at[idx].set(status, mode="drop"))
@@ -545,37 +556,39 @@ class RingFrontend:
         """Drain every shard and fill host-side numpy lane buffers (ONE
         device transfer per leaf happens in the caller). Returns
         (per-shard request lists, staged dict | None, opcode classes)."""
-        drained = [self._drain_shard(s, self.batch)
-                   for s in range(self.n_shards)]
+        with TraceAnnotation("ring.admit"):
+            drained = [self._drain_shard(s, self.batch)
+                       for s in range(self.n_shards)]
         if not any(drained):
             return [], None, set()
-        s_n, b_n = self.n_shards, self.batch
-        stage = {"want": np.zeros((s_n, b_n), bool),
-                 "payload": np.zeros((s_n, b_n) + tuple(payload_shape),
-                                     np.float32),
-                 "step": np.zeros((s_n,), np.int32)}
-        for k in ("op", "volume", "page", "block", "queue", "tick", "fn",
-                  "arg"):
-            stage[k] = np.zeros((s_n, b_n), np.int32)
-        classes: Set[str] = set()
-        for s, reqs in enumerate(drained):
-            stage["step"][s] = self.step[s]
-            if reqs:
-                self.step[s] += 1
-            for i, r in enumerate(reqs):
-                classes.add(KIND_CLASS[r.kind])
-                stage["want"][s, i] = True
-                stage["op"][s, i] = KIND_TO_OP[r.kind]
-                stage["volume"][s, i] = (r.volume // s_n if r.volume >= 0
-                                         else -1)
-                stage["page"][s, i] = r.page
-                stage["block"][s, i] = r.block
-                stage["queue"][s, i] = r.req_id % self.n_queues
-                stage["tick"][s, i] = getattr(r, "tick", 0)
-                stage["fn"][s, i] = getattr(r, "fnid", 0)
-                stage["arg"][s, i] = getattr(r, "arg", 0)
-                if r.payload is not None:
-                    stage["payload"][s, i] = np.asarray(r.payload)
+        with TraceAnnotation("ring.stage"):
+            s_n, b_n = self.n_shards, self.batch
+            stage = {"want": np.zeros((s_n, b_n), bool),
+                     "payload": np.zeros((s_n, b_n) + tuple(payload_shape),
+                                         np.float32),
+                     "step": np.zeros((s_n,), np.int32)}
+            for k in ("op", "volume", "page", "block", "queue", "tick", "fn",
+                      "arg"):
+                stage[k] = np.zeros((s_n, b_n), np.int32)
+            classes: Set[str] = set()
+            for s, reqs in enumerate(drained):
+                stage["step"][s] = self.step[s]
+                if reqs:
+                    self.step[s] += 1
+                for i, r in enumerate(reqs):
+                    classes.add(KIND_CLASS[r.kind])
+                    stage["want"][s, i] = True
+                    stage["op"][s, i] = KIND_TO_OP[r.kind]
+                    stage["volume"][s, i] = (r.volume // s_n
+                                             if r.volume >= 0 else -1)
+                    stage["page"][s, i] = r.page
+                    stage["block"][s, i] = r.block
+                    stage["queue"][s, i] = r.req_id % self.n_queues
+                    stage["tick"][s, i] = getattr(r, "tick", 0)
+                    stage["fn"][s, i] = getattr(r, "fnid", 0)
+                    stage["arg"][s, i] = getattr(r, "arg", 0)
+                    if r.payload is not None:
+                        stage["payload"][s, i] = np.asarray(r.payload)
         return drained, stage, classes
 
     def drain_ring(self, payload_shape: Tuple[int, ...] = ()):
@@ -584,15 +597,16 @@ class RingFrontend:
         drained, st, classes = self._stage(payload_shape)
         if st is None:
             return [], None, set()
-        sqe = SQE(want=jnp.asarray(st["want"]), op=jnp.asarray(st["op"]),
-                  volume=jnp.asarray(st["volume"]),
-                  page=jnp.asarray(st["page"]),
-                  block=jnp.asarray(st["block"]),
-                  payload=jnp.asarray(st["payload"]),
-                  queue=jnp.asarray(st["queue"]),
-                  tick=jnp.asarray(st["tick"]),
-                  fn=jnp.asarray(st["fn"]), arg=jnp.asarray(st["arg"]),
-                  step=jnp.asarray(st["step"]))
+        with TraceAnnotation("ring.upload"):
+            sqe = SQE(want=jnp.asarray(st["want"]), op=jnp.asarray(st["op"]),
+                      volume=jnp.asarray(st["volume"]),
+                      page=jnp.asarray(st["page"]),
+                      block=jnp.asarray(st["block"]),
+                      payload=jnp.asarray(st["payload"]),
+                      queue=jnp.asarray(st["queue"]),
+                      tick=jnp.asarray(st["tick"]),
+                      fn=jnp.asarray(st["fn"]), arg=jnp.asarray(st["arg"]),
+                      step=jnp.asarray(st["step"]))
         return drained, sqe, classes
 
 
@@ -602,9 +616,11 @@ class RingFrontend:
 @dataclass
 class PendingRing:
     """Completion handle from ``pump_async``: the per-lane CQE view (device
-    futures) plus the host-side request lists that rode the batch."""
+    futures) plus the host-side request lists that rode the batch, and the
+    step's number (``RingEngine.dispatches`` when it launched)."""
     reqs: List[List[Any]]
     view: CQEView
+    step: int
 
 
 class RingEngine(ControlDispatch):
@@ -614,8 +630,18 @@ class RingEngine(ControlDispatch):
     pump_async/drain/completed/read_volume), plus in-band control: snapshot,
     clone, unmap, delete_volume, fail, rebuild are *ring submissions* that
     execute inside the same jitted step as foreground I/O. One compiled
-    program exists per (batch geometry, opcode-class signature);
+    program exists per (batch geometry, opcode-class signature), jitted
+    under the tier's name (``ring_step_read_write``, ``ring_step_read``, ...:
+    the name a profiler trace's module line and the compile events show);
     ``trace_counts``/``dispatches`` pin that contract in tests.
+
+    Each pump records host spans (``jax.profiler.TraceAnnotation``, which
+    cost next to nothing unless a profiler trace is running): ``ring.admit``
+    (the shards' drains), ``ring.stage`` (the numpy lane buffers),
+    ``ring.upload`` (their host-to-device copies), ``ring.dispatch`` (device
+    state, program lookup, launch, state hand-back; ``step=`` its number),
+    ``ring.fetch`` (the blocking fetch of the step's CQE view; ``step=`` the
+    step it fetches) and ``ring.deliver`` (per-lane completion, requeues).
 
     Registered as ``backend="ring"`` in core/backends.py — the only backend
     whose submission path (``data_kinds``) accepts control opcodes.
@@ -651,6 +677,9 @@ class RingEngine(ControlDispatch):
         self._ctl_seq = 1 << 30      # control-op request ids (own queue slot)
         self.completed = 0
         self.dispatches = 0
+        # host totals of CQ.work and the last raw (wrapping) device reading
+        self._work = np.zeros(2, np.int64)
+        self._work_raw = np.zeros(2, np.int64)
         self.trace_counts: Dict[Tuple[str, ...], int] = {}
         self._steps: Dict[Tuple[str, ...], Any] = {}
 
@@ -712,14 +741,17 @@ class RingEngine(ControlDispatch):
                 table, cq, _, _, _, _, view = mapped(
                     table, cq, states, pools, page_revs, batch, rr, healthy)
                 return table, cq, view
-            fn = jax.jit(stepped, donate_argnums=(0, 1))
+            donate = (0, 1)
         else:
             def stepped(table, cq, states, pools, page_revs, batch, rr,
                         healthy):
                 self.trace_counts[cache_key] += 1
                 return mapped(table, cq, states, pools, page_revs, batch,
                               rr, healthy)
-            fn = jax.jit(stepped, donate_argnums=(0, 1, 2, 3, 4))
+            donate = (0, 1, 2, 3, 4)
+        # the tier names the program (jit_ring_step_read_write, ...)
+        stepped.__name__ = stepped.__qualname__ = "ring_step_" + "_".join(key)
+        fn = jax.jit(stepped, donate_argnums=donate)
         self._steps[cache_key] = fn
         return fn, key
 
@@ -831,66 +863,86 @@ class RingEngine(ControlDispatch):
             self.cfg.payload_shape)
         if batch is None:
             return None
-        if self.backend is None:
-            states, pools, page_revs = (), (), ()
-            healthy = jnp.ones((self.n_shards, 1), bool)
-            rr = jnp.zeros((self.n_shards,), jnp.int32)
-        else:
-            states, pools, healthy = self.backend.device_state()
-            page_revs = self.backend.device_page_revs()
-            rr = self.backend.bump_rr()
-        step, key = self._get_step(classes)
         self.dispatches += 1
-        read_only = key == ("read",)
-        if read_only:
-            table, cq, view = step(self.frontend.table, self.cq, states,
-                                   pools, page_revs, batch, rr, healthy)
-        else:
-            table, cq, states, pools, page_revs, healthy, view = step(
-                self.frontend.table, self.cq, states, pools, page_revs,
-                batch, rr, healthy)
-            if self.backend is not None:
-                self.backend.set_device_state(states, pools)
-                self.backend.set_device_page_revs(page_revs)
-                if "repl" in key:
-                    # only the repl program can change health; adopting on
-                    # every pump would mark the host mirror stale and make
-                    # each .healthy access pay a device sync for nothing
-                    self.backend.adopt_health(healthy)
-        self.frontend.table = table
-        self.cq = cq
-        return PendingRing(reqs=reqs, view=view)
+        with TraceAnnotation("ring.dispatch", step=self.dispatches):
+            if self.backend is None:
+                states, pools, page_revs = (), (), ()
+                healthy = jnp.ones((self.n_shards, 1), bool)
+                rr = jnp.zeros((self.n_shards,), jnp.int32)
+            else:
+                states, pools, healthy = self.backend.device_state()
+                page_revs = self.backend.device_page_revs()
+                rr = self.backend.bump_rr()
+            step, key = self._get_step(classes)
+            if key == ("read",):
+                table, cq, view = step(self.frontend.table, self.cq, states,
+                                       pools, page_revs, batch, rr, healthy)
+            else:
+                table, cq, states, pools, page_revs, healthy, view = step(
+                    self.frontend.table, self.cq, states, pools, page_revs,
+                    batch, rr, healthy)
+                if self.backend is not None:
+                    self.backend.set_device_state(states, pools)
+                    self.backend.set_device_page_revs(page_revs)
+                    if "repl" in key:
+                        # only the repl program can change health; adopting
+                        # on every pump would mark the host mirror stale and
+                        # make each .healthy access pay a device sync for
+                        # nothing
+                        self.backend.adopt_health(healthy)
+            self.frontend.table = table
+            self.cq = cq
+        return PendingRing(reqs=reqs, view=view, step=self.dispatches)
 
     def _complete(self, p: PendingRing) -> int:
         """The pump's single host hop: fetch the per-lane CQE view, deliver
         result/status/latency, requeue not-admitted requests."""
         v = p.view
-        ok, status, value, latency, reads = jax.device_get(
-            (v.ok, v.status, v.value, v.latency, v.reads))
-        done = 0
-        requeues = []
-        for s, shard_reqs in enumerate(p.reqs):
-            for i, r in enumerate(shard_reqs):
-                if not ok[s][i]:
-                    requeues.append(r)
-                    continue
-                r.status = int(status[s][i])
-                r.latency = int(latency[s][i])
-                if r.kind == "read":
-                    r.result = reads[s, i]
-                elif r.kind == "snapshot":
-                    r.result = int(value[s][i])
-                elif r.kind == "clone":
-                    local = int(value[s][i])
-                    r.result = (local * self.n_shards + s if local >= 0
-                                else -1)
-                elif r.kind == "compute":
-                    # (scalar result, CQ payload lanes) — blockdev wraps it
-                    r.result = (int(value[s][i]), reads[s, i])
-                done += 1
-        self.frontend.requeue_all(requeues)
-        self.completed += done
+        with TraceAnnotation("ring.fetch", step=p.step):
+            ok, status, value, latency, reads = jax.device_get(
+                (v.ok, v.status, v.value, v.latency, v.reads))
+        with TraceAnnotation("ring.deliver"):
+            done = 0
+            requeues = []
+            for s, shard_reqs in enumerate(p.reqs):
+                for i, r in enumerate(shard_reqs):
+                    if not ok[s][i]:
+                        requeues.append(r)
+                        continue
+                    r.status = int(status[s][i])
+                    r.latency = int(latency[s][i])
+                    if r.kind == "read":
+                        r.result = reads[s, i]
+                    elif r.kind == "snapshot":
+                        r.result = int(value[s][i])
+                    elif r.kind == "clone":
+                        local = int(value[s][i])
+                        r.result = (local * self.n_shards + s if local >= 0
+                                    else -1)
+                    elif r.kind == "compute":
+                        # (scalar result, CQ payload lanes) — blockdev wraps
+                        r.result = (int(value[s][i]), reads[s, i])
+                    done += 1
+            self.frontend.requeue_all(requeues)
+            self.completed += done
         return done
+
+    def work_counters(self) -> Dict[str, int]:
+        """The ``dbs_rw_write`` kernel's traffic since the engine was built,
+        summed over shards and replicas: ``write_rows`` (extent-row fetches
+        plus row write-backs its grid's index maps requested, a row that
+        repeats the previous grid step's counting none; each row is
+        ``page_blocks`` blocks of the pool's dtype) and
+        ``write_kernel_calls`` (one payload fetch each). The device keeps
+        int32 totals in ``CQ.work`` that wrap; this reads them (a device
+        sync) and adds the deltas modulo 2**32 to host totals, so call it at
+        least once per 2**31 rows."""
+        raw = np.asarray(jax.device_get(self.cq.work), np.int64)
+        raw = raw.reshape(-1, 2).sum(axis=0) % (1 << 32)
+        self._work += (raw - self._work_raw) % (1 << 32)
+        self._work_raw = raw
+        return {"write_rows": int(self._work[0]),
+                "write_kernel_calls": int(self._work[1])}
 
     def pump(self) -> int:
         p = self.pump_async()

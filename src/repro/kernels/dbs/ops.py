@@ -104,6 +104,25 @@ def _route_writes(ops, page, block_offsets, dump):
     return src, dst, lane_of
 
 
+def _row_moves(rows):
+    """Row DMAs a 1-D grid's index map requests over the per-step row ids
+    ``rows``: one per run of equal ids, since the Pallas pipeline neither
+    re-fetches an input block nor writes back an output block whose index
+    repeats the previous grid step's."""
+    return 1 + jnp.sum(rows[1:] != rows[:-1]).astype(jnp.int32)
+
+
+def dbs_rw_write_rows(pool, ops, block_offsets):
+    """Extent rows one engine-convention ``dbs_rw_write_pool`` call moves:
+    source-row fetches plus destination-row write-backs, counted from the
+    same routed ``src``/``dst`` vectors the kernel receives. Each row is
+    ``page * D`` pool elements; the payload is fetched once per call on top
+    (its index map is constant)."""
+    e, page = pool.shape[:2]
+    src, dst, _ = _route_writes(ops, page, block_offsets, e - 1)
+    return _row_moves(src) + _row_moves(dst)
+
+
 def dbs_rw_write_pool(pool, ops, payload, block_offsets, *, interpret=None,
                       scratch=True):
     """The whole write data plane — CoW copy + payload block stores — as one

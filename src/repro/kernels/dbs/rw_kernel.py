@@ -81,6 +81,7 @@ def dbs_rw_write(pool, src, dst, lane_of, payload, *, interpret):
         out_shape=jax.ShapeDtypeStruct(pool.shape, pool.dtype),
         input_output_aliases={3: 0},        # pool (first tensor arg) -> out
         interpret=interpret,
+        name="dbs_rw_write",
     )(src, dst, lane_of.reshape(-1), pool, payload)
 
 
@@ -121,4 +122,5 @@ def dbs_rw_read(pool, ext, block, *, interpret):
         ),
         out_shape=jax.ShapeDtypeStruct((b, 1, d), pool.dtype),
         interpret=interpret,
+        name="dbs_rw_read",
     )(ext, jnp.clip(block, 0, page - 1), pool).reshape(b, d)

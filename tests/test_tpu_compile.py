@@ -101,3 +101,59 @@ def test_paged_attention_pool_compiles_granite_widths(one_chip):
         _shape(one_chip, (ext, page, 2 * layers, kv, hd)),
         _shape(one_chip, (seqs, n_pages), jnp.int32),
         _shape(one_chip, (seqs,), jnp.int32))
+
+
+def test_dbs_rw_kernels_carry_their_names(one_chip):
+    """Each kernel's custom call is named, so a chip trace and the compile
+    events can tell it from the others."""
+    write = jax.jit(
+        lambda pool, ops, pay, blk: dbs_rw_write_pool(pool, ops, pay, blk,
+                                                      interpret=False)
+    ).lower(_shape(one_chip, (E, PAGE, D)), _write_ops(one_chip),
+            _shape(one_chip, (B, D)), _shape(one_chip, (B,), jnp.int32))
+    read = jax.jit(
+        lambda pool, ext, blk: dbs_rw_read_pool(pool, ext, blk,
+                                                interpret=False)
+    ).lower(_shape(one_chip, (E, PAGE, D)), _shape(one_chip, (B,), jnp.int32),
+            _shape(one_chip, (B,), jnp.int32))
+    assert 'kernel_name = "dbs_rw_write"' in write.as_text()
+    assert 'kernel_name = "dbs_rw_read"' in read.as_text()
+
+
+def test_ring_step_programs_are_named_by_their_tier(one_chip, monkeypatch):
+    """The ring step of a small manager, lowered for the chip with the
+    compiled kernels: each program's module is named by its tier, and
+    carries the kernels that tier runs by name."""
+    from repro.core.blockdev import VolumeManager
+    from repro.kernels.dbs import ops
+
+    mgr = VolumeManager(backend="ring", n_replicas=3, payload_elems=128,
+                        page_blocks=8, n_extents=16, max_pages=4, batch=8,
+                        kernel="pallas")
+    impl = mgr.engine.impl
+    get_step = impl._get_step
+    shapes = {}
+
+    def spy(classes):
+        fn, key = get_step(classes)
+
+        def record(*args):
+            shapes[key] = jax.tree.map(
+                lambda x: _shape(one_chip, x.shape, x.dtype), args)
+            return fn(*args)
+        return record, key
+
+    monkeypatch.setattr(impl, "_get_step", spy)
+    vol = mgr.create()
+    vol.write(0, bytes(range(128)))
+    assert vol.read(0, 128) == bytes(range(128))
+    monkeypatch.setattr(impl, "_get_step", get_step)
+    monkeypatch.setattr(ops, "default_interpret", lambda: False)
+    impl._steps.clear()
+    assert set(shapes) == {("read", "write"), ("read",)}
+    for key, args in shapes.items():
+        fn, _ = impl._get_step(set(key))
+        text = fn.lower(*args).as_text()
+        assert "module @jit_ring_step_" + "_".join(key) in text
+        assert ('kernel_name = "dbs_rw_write"' in text) == ("write" in key)
+        assert 'kernel_name = "dbs_rw_read"' in text
