@@ -498,6 +498,11 @@ class VolumeManager:
           (``RingEngine.work_counters``; reading them syncs the device), so
           it moves ``4 * (write_rows * page_blocks + write_kernel_calls *
           batch) * block_bytes`` HBM bytes (one float32 lane per byte);
+        - on ``backend="ring"``, ``upload_transfers``, ``upload_bytes`` and
+          ``upload_payload_skips``: the pump's packed SQE uploads, one
+          host-to-device transfer per dispatched step, their bytes, and
+          those that left the payload out because the step's program was
+          read-only (``RingEngine.upload_counters``; no device sync);
         - ``slots_active``, ``journal``, ``tier`` where the backend has them.
 
         While a profiler trace runs (``jax.profiler``), the pump records
@@ -513,6 +518,7 @@ class VolumeManager:
             out["fence_steps"] = self._fence_steps
         if hasattr(impl, "work_counters"):
             out.update(impl.work_counters())
+            out.update(impl.upload_counters())
         table = getattr(self.engine.frontend, "table", None)
         if table is not None:
             from repro.core import slots

@@ -271,7 +271,8 @@ class ShardedFrontend:
         shard s's request i rode lane (s, i); shards with no traffic
         contribute all-inert (want=False) rows, so the program geometry
         never depends on which shards are busy. One device transfer per
-        leaf, as always on the pump path.
+        leaf, from the named views of the ring stage's packed buffer (the
+        ring pump itself sends that buffer as one transfer).
         """
         drained, st, classes = self.ring._stage(payload_shape)
         if st is None:

@@ -139,6 +139,41 @@ class SQE:
     step: jnp.ndarray       # ()   int32 admission step (this pump's tick)
 
 
+# The packed upload: one int32 row per shard carries a whole SQE. The header
+# holds the lane fields below in this order, each B words wide (``want`` as
+# 0/1), then the ``step`` word, padded to whole 128-word lane tiles so the
+# payload starts on a tile; the payload's float32 bits follow,
+# B * prod(payload_shape) words.
+SQE_LANES = ("want", "op", "volume", "page", "block", "queue", "tick", "fn",
+             "arg")
+LANE_TILE = 128
+
+
+def header_words(b_n: int) -> int:
+    """Width of the packed upload's header for a B-lane batch."""
+    return -(-(len(SQE_LANES) * b_n + 1) // LANE_TILE) * LANE_TILE
+
+
+def unpack_sqe(packed: jnp.ndarray, b_n: int,
+               payload_shape: Tuple[int, ...]) -> SQE:
+    """Rebuild the stacked (S, B, ...) SQE from its packed (S, W) int32
+    upload, inside the jitted step: slices for the lane fields, the payload
+    bit-cast back to float32. An upload of the header alone (the read tier's,
+    whose program never reads payloads) gets zero payloads."""
+    s_n, h = packed.shape[0], header_words(b_n)
+    lanes = {name: packed[:, k * b_n:(k + 1) * b_n]
+             for k, name in enumerate(SQE_LANES)}
+    lanes["want"] = lanes["want"] != 0
+    shape = (s_n, b_n) + tuple(payload_shape)
+    if packed.shape[1] > h:
+        payload = jax.lax.bitcast_convert_type(
+            packed[:, h:], jnp.float32).reshape(shape)
+    else:
+        payload = jnp.zeros(shape, jnp.float32)
+    return SQE(payload=payload, step=packed[:, len(SQE_LANES) * b_n],
+               **lanes)
+
+
 @jax.tree_util.register_dataclass
 @dataclass
 class CQ:
@@ -553,9 +588,14 @@ class RingFrontend:
         return reqs
 
     def _stage(self, payload_shape: Tuple[int, ...] = ()):
-        """Drain every shard and fill host-side numpy lane buffers (ONE
-        device transfer per leaf happens in the caller). Returns
-        (per-shard request lists, staged dict | None, opcode classes)."""
+        """Drain every shard and fill ONE fresh host buffer for the pump:
+        ``staged["packed"]``, int32 (S, W), laid out as ``unpack_sqe``
+        reads it. The other entries are numpy views of it under the SQE's
+        field names (``payload`` a float32 view, so payloads are copied once
+        and keep their bits; ``want`` a bool copy), for the legacy adapters
+        (core/frontend.py). The buffer is never reused: an upload may alias
+        it. Returns (per-shard request lists, staged dict | None, opcode
+        classes)."""
         with TraceAnnotation("ring.admit"):
             drained = [self._drain_shard(s, self.batch)
                        for s in range(self.n_shards)]
@@ -563,13 +603,14 @@ class RingFrontend:
             return [], None, set()
         with TraceAnnotation("ring.stage"):
             s_n, b_n = self.n_shards, self.batch
-            stage = {"want": np.zeros((s_n, b_n), bool),
-                     "payload": np.zeros((s_n, b_n) + tuple(payload_shape),
-                                         np.float32),
-                     "step": np.zeros((s_n,), np.int32)}
-            for k in ("op", "volume", "page", "block", "queue", "tick", "fn",
-                      "arg"):
-                stage[k] = np.zeros((s_n, b_n), np.int32)
+            h = header_words(b_n)
+            p_n = int(np.prod(payload_shape, dtype=np.int64))
+            buf = np.zeros((s_n, h + b_n * p_n), np.int32)
+            stage = {name: buf[:, k * b_n:(k + 1) * b_n]
+                     for k, name in enumerate(SQE_LANES)}
+            stage["step"] = buf[:, len(SQE_LANES) * b_n]
+            stage["payload"] = buf[:, h:].view(np.float32).reshape(
+                (s_n, b_n) + tuple(payload_shape))
             classes: Set[str] = set()
             for s, reqs in enumerate(drained):
                 stage["step"][s] = self.step[s]
@@ -577,7 +618,7 @@ class RingFrontend:
                     self.step[s] += 1
                 for i, r in enumerate(reqs):
                     classes.add(KIND_CLASS[r.kind])
-                    stage["want"][s, i] = True
+                    stage["want"][s, i] = 1
                     stage["op"][s, i] = KIND_TO_OP[r.kind]
                     stage["volume"][s, i] = (r.volume // s_n
                                              if r.volume >= 0 else -1)
@@ -589,25 +630,26 @@ class RingFrontend:
                     stage["arg"][s, i] = getattr(r, "arg", 0)
                     if r.payload is not None:
                         stage["payload"][s, i] = np.asarray(r.payload)
+            stage["want"] = stage["want"] != 0
+            stage["packed"] = buf
         return drained, stage, classes
 
     def drain_ring(self, payload_shape: Tuple[int, ...] = ()):
-        """The unified drain: one stacked (S, B, ...) SQE batch per pump.
-        Returns (per-shard request lists, SQE | None, opcode classes)."""
+        """The unified drain: one stacked (S, B, ...) SQE batch per pump,
+        sent as ONE host-to-device transfer of ``_stage``'s packed buffer
+        (``unpack_sqe`` rebuilds the SQE in the step). A batch whose program
+        is the read tier (``RingEngine._canon``) takes no payload, so only
+        the header columns go. Returns (per-shard request lists, packed
+        device array | None, opcode classes)."""
         drained, st, classes = self._stage(payload_shape)
         if st is None:
             return [], None, set()
         with TraceAnnotation("ring.upload"):
-            sqe = SQE(want=jnp.asarray(st["want"]), op=jnp.asarray(st["op"]),
-                      volume=jnp.asarray(st["volume"]),
-                      page=jnp.asarray(st["page"]),
-                      block=jnp.asarray(st["block"]),
-                      payload=jnp.asarray(st["payload"]),
-                      queue=jnp.asarray(st["queue"]),
-                      tick=jnp.asarray(st["tick"]),
-                      fn=jnp.asarray(st["fn"]), arg=jnp.asarray(st["arg"]),
-                      step=jnp.asarray(st["step"]))
-        return drained, sqe, classes
+            packed = st["packed"]
+            if RingEngine._canon(classes) == ("read",):
+                packed = packed[:, :header_words(self.batch)]
+            packed = jax.device_put(packed)
+        return drained, packed, classes
 
 
 # ---------------------------------------------------------------------------
@@ -637,9 +679,10 @@ class RingEngine(ControlDispatch):
 
     Each pump records host spans (``jax.profiler.TraceAnnotation``, which
     cost next to nothing unless a profiler trace is running): ``ring.admit``
-    (the shards' drains), ``ring.stage`` (the numpy lane buffers),
-    ``ring.upload`` (their host-to-device copies), ``ring.dispatch`` (device
-    state, program lookup, launch, state hand-back; ``step=`` its number),
+    (the shards' drains), ``ring.stage`` (the one packed host buffer),
+    ``ring.upload`` (its single host-to-device transfer, the header alone
+    for the read tier), ``ring.dispatch`` (device state, program lookup,
+    launch, state hand-back; ``step=`` its number),
     ``ring.fetch`` (the blocking fetch of the step's CQE view; ``step=`` the
     step it fetches) and ``ring.deliver`` (per-lane completion, requeues).
 
@@ -677,6 +720,10 @@ class RingEngine(ControlDispatch):
         self._ctl_seq = 1 << 30      # control-op request ids (own queue slot)
         self.completed = 0
         self.dispatches = 0
+        # host totals of the packed SQE uploads (``upload_counters``)
+        self.upload_transfers = 0
+        self.upload_bytes = 0
+        self.upload_payload_skips = 0
         # host totals of CQ.work and the last raw (wrapping) device reading
         self._work = np.zeros(2, np.int64)
         self._work_raw = np.zeros(2, np.int64)
@@ -723,6 +770,8 @@ class RingEngine(ControlDispatch):
             return self._steps[cache_key], key
         self.trace_counts.setdefault(cache_key, 0)
         read_only = key == ("read",)
+        unpack = partial(unpack_sqe, b_n=self.frontend.batch,
+                         payload_shape=tuple(self.cfg.payload_shape))
         core = partial(ring_step_core, classes=key,
                        null_backend=self.cfg.null_backend,
                        null_storage=self.cfg.null_storage,
@@ -735,19 +784,20 @@ class RingEngine(ControlDispatch):
             # returning them would materialize pass-through copies
             # (fused_step_read's rationale); only the table and the CQ
             # round-trip.
-            def stepped(table, cq, states, pools, page_revs, batch, rr,
+            def stepped(table, cq, states, pools, page_revs, packed, rr,
                         healthy):
                 self.trace_counts[cache_key] += 1
                 table, cq, _, _, _, _, view = mapped(
-                    table, cq, states, pools, page_revs, batch, rr, healthy)
+                    table, cq, states, pools, page_revs, unpack(packed), rr,
+                    healthy)
                 return table, cq, view
             donate = (0, 1)
         else:
-            def stepped(table, cq, states, pools, page_revs, batch, rr,
+            def stepped(table, cq, states, pools, page_revs, packed, rr,
                         healthy):
                 self.trace_counts[cache_key] += 1
-                return mapped(table, cq, states, pools, page_revs, batch,
-                              rr, healthy)
+                return mapped(table, cq, states, pools, page_revs,
+                              unpack(packed), rr, healthy)
             donate = (0, 1, 2, 3, 4)
         # the tier names the program (jit_ring_step_read_write, ...)
         stepped.__name__ = stepped.__qualname__ = "ring_step_" + "_".join(key)
@@ -859,10 +909,14 @@ class RingEngine(ControlDispatch):
         """Admit one opcode-tagged batch per shard and launch the ring step;
         do NOT block. Control lanes execute inside the same program as the
         data lanes — no host dispatch per control op."""
-        reqs, batch, classes = self.frontend.drain_ring(
+        reqs, packed, classes = self.frontend.drain_ring(
             self.cfg.payload_shape)
-        if batch is None:
+        if packed is None:
             return None
+        self.upload_transfers += 1
+        self.upload_bytes += packed.nbytes
+        self.upload_payload_skips += (
+            packed.shape[1] == header_words(self.frontend.batch))
         self.dispatches += 1
         with TraceAnnotation("ring.dispatch", step=self.dispatches):
             if self.backend is None:
@@ -876,11 +930,11 @@ class RingEngine(ControlDispatch):
             step, key = self._get_step(classes)
             if key == ("read",):
                 table, cq, view = step(self.frontend.table, self.cq, states,
-                                       pools, page_revs, batch, rr, healthy)
+                                       pools, page_revs, packed, rr, healthy)
             else:
                 table, cq, states, pools, page_revs, healthy, view = step(
                     self.frontend.table, self.cq, states, pools, page_revs,
-                    batch, rr, healthy)
+                    packed, rr, healthy)
                 if self.backend is not None:
                     self.backend.set_device_state(states, pools)
                     self.backend.set_device_page_revs(page_revs)
@@ -943,6 +997,15 @@ class RingEngine(ControlDispatch):
         self._work_raw = raw
         return {"write_rows": int(self._work[0]),
                 "write_kernel_calls": int(self._work[1])}
+
+    def upload_counters(self) -> Dict[str, int]:
+        """The packed SQE uploads since the engine was built (host totals,
+        no device sync): ``upload_transfers`` (one per dispatched step),
+        ``upload_bytes`` and ``upload_payload_skips`` (read-tier uploads
+        that left the payload out)."""
+        return {"upload_transfers": self.upload_transfers,
+                "upload_bytes": self.upload_bytes,
+                "upload_payload_skips": self.upload_payload_skips}
 
     def pump(self) -> int:
         p = self.pump_async()

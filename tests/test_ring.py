@@ -18,6 +18,9 @@ Contracts:
    ``device_get`` per pump even with control lanes aboard.
 5. satellites: unified ``Request.result``/``status`` across every comm
    mode; ``ChainedReplicas`` volume-id agreement and null-storage rr fixes.
+6. **packed upload** — a pump sends its SQE as one host-to-device transfer
+   (header only for the read tier) and the step's results match the
+   leaf-by-leaf SQE bit for bit, float payload bits included.
 """
 import dataclasses
 
@@ -672,3 +675,163 @@ def test_ladder_has_ring_column():
                            volume=vols[i % 2], page=i % 32, block=i % 8,
                            payload=jnp.ones(PAY)))
     assert eng.drain() == 24
+
+
+# ---------------------------------------------------------------------------
+# 6. the packed SQE upload: one host-to-device transfer per pump
+# ---------------------------------------------------------------------------
+# float32 lanes that are no byte values: negatives, fractions, a subnormal,
+# -0.0, an infinity and NaNs that carry payload bits (quiet, signalling,
+# negative)
+_ODD_BITS = np.array([0xBFC00000, 0x3E800000, 0x00000001, 0x80000000,
+                      0xFF800000, 0x7FC00123, 0x7F800001, 0xFFC0BEEF],
+                     np.uint32)
+
+
+def _odd(i: int) -> np.ndarray:
+    lanes = _ODD_BITS.view(np.float32).copy()
+    lanes[:2] -= np.float32(i)               # -1.5 - i, 0.25 - i
+    return lanes
+
+
+def _tier_traffic(tier, vols, base):
+    """Requests whose batch runs ``tier``'s program: reads of pages the
+    warm-up wrote, plus the writes and control/compute ops the tier adds."""
+    reqs = [Request(req_id=base + i, kind="read", volume=vols[i % 2],
+                    page=i % 4, block=i % 8) for i in range(4)]
+    if tier != "read":
+        reqs += [Request(req_id=base + 10 + i, kind="write",
+                         volume=vols[i % 2], page=4 + i, block=i % 8,
+                         payload=_odd(base + i)) for i in range(4)]
+    if tier == "compute":
+        reqs += [Request(req_id=base + 20, kind="compute", fn="checksum",
+                         volume=vols[0], page=0, block=2),
+                 Request(req_id=base + 21, kind="compute",
+                         fn="verify_on_read", volume=vols[1], page=1,
+                         block=3, arg=7)]
+    elif tier == "vol":
+        reqs += [Request(req_id=base + 20, kind="snapshot", volume=vols[0]),
+                 Request(req_id=base + 21, kind="unmap", volume=vols[1],
+                         page=2)]
+    elif tier == "repl":
+        reqs += [Request(req_id=base + 20, kind="fail", shard=0, block=1)]
+    return reqs
+
+
+def _same_bits(got, want, what):
+    assert jax.tree.structure(got) == jax.tree.structure(want), what
+    for g, w in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        g, w = np.asarray(g), np.asarray(w)
+        assert (g.dtype, g.shape) == (w.dtype, w.shape), what
+        assert g.tobytes() == w.tobytes(), what
+
+
+@pytest.mark.parametrize("shards", [1, 2])
+@pytest.mark.parametrize("tier", ["read", "write", "compute", "vol", "repl"])
+def test_packed_upload_matches_the_eleven_leaf_sqe(tier, shards,
+                                                   monkeypatch):
+    """One pump through the packed upload leaves the same CQE view, CQ,
+    slot table, replica states, pools, watermarks and health, bit for bit,
+    as the step run on the pre-pump state with the SQE uploaded leaf by leaf
+    from ``_stage``'s named views."""
+    from functools import partial
+
+    from repro.core import ring
+    eng = Engine(_cfg(n_shards=shards, n_queues=1))
+    pool, fe = eng.pool, eng.pool.frontend
+    vols = [eng.create_volume() for _ in range(2)]
+    for i in range(8):
+        eng.submit(Request(req_id=100 + i, kind="write", volume=vols[i % 2],
+                           page=i % 4, block=i % 8, payload=_odd(i)))
+    assert eng.drain() == 8
+    for r in _tier_traffic(tier, vols, 1000):
+        eng.submit(r)
+
+    staged = []
+    stage = fe._stage
+    monkeypatch.setattr(fe, "_stage",
+                        lambda *a: staged.append(stage(*a)) or staged[-1])
+    backend = pool.backend
+    states, pools, healthy = backend.device_state()
+    before = jax.tree.map(jnp.copy, (fe.table, pool.cq, states, pools,
+                                     backend.device_page_revs(),
+                                     backend._rr, healthy))
+    p = pool.pump_async()
+    key = pool._canon(staged[0][2])
+    assert tier in key
+    st = staged[0][1]
+    leaves = ring.SQE(**{k: jnp.asarray(st[k])
+                         for k in ring.SQE_LANES + ("payload", "step")})
+    assert st["want"].dtype == bool and leaves.payload.dtype == jnp.float32
+    core = partial(ring.ring_step_core, classes=key, kernel=pool._kernel,
+                   compute_tail=pool._compute_tail)
+    table0, cq0, states0, pools0, prs0, rr0, healthy0 = before
+    ref = jax.jit(ring.vmap_shards(core, shards))(
+        table0, cq0, states0, pools0, prs0, leaves, rr0, healthy0)
+    states1, pools1, healthy1 = backend.device_state()
+    got = (fe.table, pool.cq, states1, pools1, backend.device_page_revs(),
+           healthy1, p.view)
+    for name, g, w in zip(("table", "cq", "states", "pools", "page_revs",
+                           "healthy", "view"), got, ref):
+        _same_bits(g, w, name)
+    # the comparison is not vacuous: the reads returned the warm-up's odd
+    # float lanes, NaN payload bits included
+    reads = np.asarray(p.view.reads).view(np.uint32)
+    assert (reads == 0x7FC00123).any() and (reads == 0xFFC0BEEF).any()
+
+
+class _Counting:
+    """A module stand-in that records calls of some of its functions."""
+
+    def __init__(self, module, names, calls):
+        self._module, self._names, self._calls = module, names, calls
+
+    def __getattr__(self, name):
+        fn = getattr(self._module, name)
+        if name not in self._names:
+            return fn
+
+        def counted(*a, **kw):
+            self._calls.append(name)
+            return fn(*a, **kw)
+        return counted
+
+
+@pytest.mark.parametrize("shards", [1, 2])
+def test_a_pump_makes_one_host_to_device_transfer(shards, monkeypatch):
+    from repro.core import ring
+    eng = Engine(_cfg(n_shards=shards))
+    pool = eng.pool
+    vols = [eng.create_volume() for _ in range(2)]
+
+    def submit(kind, base):
+        for i in range(6):
+            eng.submit(Request(req_id=base + i, kind=kind,
+                               volume=vols[i % 2], page=i, block=i % 8,
+                               payload=_odd(i) if kind == "write" else None))
+
+    submit("write", 0)
+    assert eng.drain() == 6
+    submit("read", 10)
+    assert eng.drain() == 6                  # both programs compiled
+    calls = []
+    monkeypatch.setattr(ring, "jax", _Counting(jax, {"device_put"}, calls))
+    monkeypatch.setattr(ring, "jnp", _Counting(jnp, {"asarray"}, calls))
+    h = ring.header_words(16)
+    whole = shards * (h + 16 * PAY[0]) * 4
+    for kind, nbytes, skips in (("write", whole, 0),
+                                ("read", shards * h * 4, 1)):
+        c0 = pool.upload_counters()
+        d0 = pool.dispatches
+        submit(kind, 100)
+        calls.clear()
+        assert pool.pump() == 6
+        assert calls == ["device_put"], kind
+        c1 = pool.upload_counters()
+        assert pool.dispatches - d0 == 1
+        assert c1["upload_transfers"] - c0["upload_transfers"] == 1
+        assert c1["upload_bytes"] - c0["upload_bytes"] == nbytes, kind
+        assert (c1["upload_payload_skips"]
+                - c0["upload_payload_skips"]) == skips, kind
+    assert pool.trace_counts == {("read", "write"): 1, ("read",): 1}
+    assert pool.upload_transfers == pool.dispatches

@@ -2,7 +2,8 @@
 hazard fence and Python's collector (recorded while a profiler trace
 runs), the names of the ring step's programs, and the counters
 ``VolumeManager.stats()`` reports — the hazard fence's flushes and steps
-and the ``dbs_rw_write`` kernel's extent-row traffic."""
+the ``dbs_rw_write`` kernel's extent-row traffic, and the pump's packed
+SQE uploads."""
 import dataclasses
 import gc
 import glob
@@ -130,9 +131,12 @@ def test_write_counters_take_deltas_modulo_2_32():
     assert _counts(mgr) == (2 ** 31 + 2, 2 ** 31)
 
 
+UPLOADS = {"upload_transfers", "upload_bytes", "upload_payload_skips"}
+
+
 @pytest.mark.parametrize("backend,keys", [
     ("ring", {"fence_flushes", "fence_steps", "write_rows",
-              "write_kernel_calls"}),
+              "write_kernel_calls"} | UPLOADS),
     # the fused step counts no steps and has no CQ
     ("fused", {"fence_flushes"}),
 ])
@@ -144,10 +148,14 @@ def test_stats_carry_the_counters(backend, keys):
     vol.pwrite(0, _block(2))
     mgr.flush()
     s = mgr.stats()
-    assert set(s) & {"fence_flushes", "fence_steps", "write_rows",
-                     "write_kernel_calls"} == keys
+    assert set(s) & ({"fence_flushes", "fence_steps", "write_rows",
+                      "write_kernel_calls"} | UPLOADS) == keys
     assert s["fence_flushes"] == 1
     assert s.get("fence_steps", 1) == 1
+    if UPLOADS <= keys:                      # one upload per step, all writes
+        assert s["upload_transfers"] == mgr.engine.impl.dispatches
+        assert s["upload_payload_skips"] == 0
+        assert s["upload_bytes"] > 0
 
 
 # ------------------------------------------------------------ the spans
