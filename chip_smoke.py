@@ -19,10 +19,16 @@ compile and run seconds, op counts and mismatch counts, then, as its last
 line, ``{"ok": true, "device": {...}}``. Without a TPU it exits non-zero
 and prints no result. Run from the repository root:
 
-    python chip_smoke.py
+    python chip_smoke.py [--replica-chips 3]
+
+``--replica-chips 3`` runs the same scenario with each replica on its own
+chip (``VolumeManager(replica_chips=3)``: replica r on chip r, writes
+mirrored from chip 0 inside the ring step); every replica's pool is then
+checked on its own chip, and the peak bytes are printed per chip.
 """
 from __future__ import annotations
 
+import argparse
 import json
 import os
 import sys
@@ -73,7 +79,9 @@ def smoke(geometry: dict, *, n_volumes: int, write_bytes: int,
     jax.block_until_ready(pools)
     out = {"kernel": mgr.engine._kernel,
            "pool_bytes": sum(int(p.nbytes) for p in pools),
-           "n_pools": len(pools), "setup_s": time.perf_counter() - t0}
+           "n_pools": len(pools), "setup_s": time.perf_counter() - t0,
+           "pool_devices": [sorted(d.id for d in p.devices())
+                            for p in pools]}
     log(f"manager: {mgr!r}, {out['n_pools']} replica pools, "
         f"{out['pool_bytes']} pool bytes")
 
@@ -146,11 +154,20 @@ def smoke(geometry: dict, *, n_volumes: int, write_bytes: int,
     return out
 
 
-def main() -> int:
+def main(argv=None) -> int:
+    ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
+    ap.add_argument("--replica-chips", type=int, default=1, choices=(1, 3),
+                    help="1: every replica on chip 0; 3: replica r on chip r")
+    args = ap.parse_args(argv)
+    geometry = dict(GEOMETRY, replica_chips=args.replica_chips)
     dev = jax.devices()[0]
     if dev.platform != "tpu":
         print(f"chip_smoke: no TPU — JAX reports platform {dev.platform!r}",
               file=sys.stderr)
+        return 1
+    if len(jax.devices()) < args.replica_chips:
+        print(f"chip_smoke: --replica-chips {args.replica_chips} needs as "
+              f"many chips, JAX sees {len(jax.devices())}", file=sys.stderr)
         return 1
     cache = place_compile_cache()
     compile_s = []
@@ -161,17 +178,19 @@ def main() -> int:
               "count": len(jax.devices())}
     print(f"device: {json.dumps(device)}")
     print(f"compile cache: {cache}")
-    print(f"geometry: {json.dumps(GEOMETRY)}, volumes={N_VOLUMES}, "
+    print(f"geometry: {json.dumps(geometry)}, volumes={N_VOLUMES}, "
           f"write_bytes={WRITE_BYTES}, seed={SEED}")
     t0 = time.perf_counter()
-    res = smoke(GEOMETRY, n_volumes=N_VOLUMES, write_bytes=WRITE_BYTES,
+    res = smoke(geometry, n_volumes=N_VOLUMES, write_bytes=WRITE_BYTES,
                 diverge_writes=DIVERGE_WRITES, seed=SEED)
     wall = time.perf_counter() - t0
     interpret = default_interpret()
-    stats = dev.memory_stats() or {}
+    peaks = [(d.memory_stats() or {}).get("peak_bytes_in_use")
+             for d in jax.devices()[:args.replica_chips]]
     print(f"kernel: {res['kernel']} interpret={interpret}")
-    print(f"pool_bytes: {res['pool_bytes']} ({res['n_pools']} replicas)")
-    print(f"peak_bytes_in_use: {stats.get('peak_bytes_in_use')}")
+    print(f"pool_bytes: {res['pool_bytes']} ({res['n_pools']} replicas, "
+          f"on devices {res['pool_devices']})")
+    print(f"peak_bytes_in_use: {peaks[0] if len(peaks) == 1 else peaks}")
     print(f"seconds: wall={wall} compile={sum(compile_s)} "
           f"run={wall - sum(compile_s)} setup={res['setup_s']} "
           f"write={res['write_s']} read={res['read_s']} "
@@ -190,10 +209,16 @@ def main() -> int:
     if res["n_pools"] != GEOMETRY["n_replicas"]:
         problems.append(f"{res['n_pools']} replica pools, want "
                         f"{GEOMETRY['n_replicas']}")
+    want = [[jax.devices()[r if args.replica_chips > 1 else 0].id]
+            for r in range(res["n_pools"])]
+    if res["pool_devices"] != want:
+        problems.append(f"replica pools on devices {res['pool_devices']}, "
+                        f"want {want}")
     if problems:
         print("chip_smoke: FAILED: " + "; ".join(problems), file=sys.stderr)
         return 1
-    print(json.dumps({"ok": True, "device": device}))
+    print(json.dumps({"ok": True, "device": device,
+                      "replica_chips": args.replica_chips}))
     return 0
 
 

@@ -296,7 +296,8 @@ class VolumeManager:
                  write_policy: str = "all", read_policy: str = "rr",
                  transport_opts: Optional[Dict[str, Any]] = None,
                  payload_shape: Optional[Tuple[int, ...]] = None,
-                 journal: Any = None, tier: Any = None):
+                 journal: Any = None, tier: Any = None,
+                 replica_chips: int = 1):
         # payload_shape overrides the byte-API's flat (payload_elems,) lane
         # layout with an arbitrary per-block tensor — the serving engine
         # stores one token's K/V for every layer in one block
@@ -315,7 +316,8 @@ class VolumeManager:
             null_storage=null_storage, cow=cow, kernel=kernel,
             transport=transport,
             write_policy=write_policy, read_policy=read_policy,
-            transport_opts=transport_opts, journal=journal, tier=tier))
+            transport_opts=transport_opts, journal=journal, tier=tier,
+            replica_chips=replica_chips))
         # durability journal (repro/durability/journal.py): the manager
         # buffers one WireMsg per mutating public-API op and group-commits
         # the buffer — ONE append + seal — at every pump boundary, BEFORE
@@ -503,6 +505,13 @@ class VolumeManager:
           host-to-device transfer per dispatched step, their bytes, and
           those that left the payload out because the step's program was
           read-only (``RingEngine.upload_counters``; no device sync);
+        - on ``backend="ring"``, ``fanout_bytes`` and ``remote_read_lanes``:
+          with the replicas on separate chips (``replica_chips``), the
+          user bytes owed to the remote replicas (written blocks x (R - 1)
+          x ``block_bytes``; the step sends them the whole packed SQE) and
+          the read lanes a remote replica served
+          (``RingEngine.replica_counters``; no device sync; 0 on one
+          chip);
         - ``slots_active``, ``journal``, ``tier`` where the backend has them.
 
         While a profiler trace runs (``jax.profiler``), the pump records
@@ -519,9 +528,12 @@ class VolumeManager:
         if hasattr(impl, "work_counters"):
             out.update(impl.work_counters())
             out.update(impl.upload_counters())
+            out.update(impl.replica_counters())
         table = getattr(self.engine.frontend, "table", None)
         if table is not None:
             from repro.core import slots
+            if hasattr(impl, "node0"):       # one copy per replica chip
+                table = impl.node0(table)
             out["slots_active"] = int(np.asarray(slots.n_active(table)))
         if self._journal is not None:
             out["journal"] = {"seq": self._journal.seq,
@@ -760,6 +772,8 @@ class VolumeManager:
         the same device computation against the replica pools
         (repro.compute.exec.device_compute)."""
         self._check_open()
+        if hasattr(self.engine.impl, "refuse"):
+            self.engine.impl.refuse("compute")
         from repro.compute import make_storage_fn, storage_fn_id
         vid = self._vid(vol)
         entry = make_storage_fn(fn)           # unknown names raise here

@@ -63,6 +63,10 @@ class EngineConfig:
                                  # (pure-jnp row composition) | copy
                                  # (dbs_copy + XLA scatter hybrid)
     n_shards: int = 1            # engine shards for comm="sharded"/"ring"
+    replica_chips: int = 1       # chips the replicas span (comm="ring"): 1
+                                 # (all on the default device) or
+                                 # n_replicas (replica r on jax.devices()[r],
+                                 # writes mirrored between the chips)
     compute_tail: int = 8        # max COMPUTE SQEs per ring batch (the
                                  # in-program storage-function scan window,
                                  # core/ring.py / compute/phase.py)
@@ -113,6 +117,10 @@ class Engine:
             raise ValueError(
                 f"unknown kernel {cfg.kernel!r} (expected auto | "
                 f"{' | '.join(available_kernels())})")
+        if cfg.replica_chips != 1 and cfg.comm != "ring":
+            raise ValueError(
+                f"replica_chips={cfg.replica_chips} (replicas on separate "
+                f"chips) needs comm='ring'; got comm={cfg.comm!r}")
         if cfg.tier is not None and cfg.comm != "fused":
             raise ValueError(
                 f"tier= (the cold-extent spill tier) needs comm='fused' — "

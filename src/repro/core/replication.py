@@ -427,6 +427,17 @@ class ShardedReplicaGroup(_Waiter):
 
     The round-robin read cursors are a device-resident (S,) array bumped
     with a device add — no host sync on the pump path.
+
+    With ``devices`` (one per replica), ``mesh`` is the one-axis
+    (``"node"``) mesh over them that the ring step is lowered on
+    (core/ring.py), and the replicas are held as ``node_arrays``: (states,
+    pools, page_revs), each leaf one global array split ``P("node")`` whose
+    node r block is replica r's (S, ...) leaf on ``devices[r]``. The ring
+    step takes and returns them as they are. Replica r's endpoint reads and
+    writes node r's blocks (``_NodeReplica``), so control messages run on
+    each replica's own chip; the read cursors and the health mask are
+    replicated over the mesh, so bumping the cursor moves nothing between
+    chips.
     """
 
     def __init__(self, n_shards: int, n_replicas: int, n_extents: int,
@@ -435,7 +446,8 @@ class ShardedReplicaGroup(_Waiter):
                  null_storage: bool = False, transport: str = "device",
                  write_policy: str = "all", read_policy: str = "rr",
                  transport_opts: Optional[Dict[str, Any]] = None,
-                 rebuild_chunk: int = REBUILD_CHUNK):
+                 rebuild_chunk: int = REBUILD_CHUNK,
+                 devices: Optional[Sequence[Any]] = None):
         _check_policies(write_policy, read_policy)   # unknown names first
         if write_policy != "all" or read_policy != "rr":
             raise ValueError(
@@ -452,18 +464,31 @@ class ShardedReplicaGroup(_Waiter):
         # that IS the device transport
         self.transport_name = "device" if transport == "local" else transport
         stack = lambda x: jnp.tile(x[None], (n_shards,) + (1,) * x.ndim)
-        # one extra extent row per pool: the fused CoW kernel's masked-lane
-        # dump (same convention as ReplicaGroup)
-        endpoints = [
-            StackedReplica(
-                state=jax.tree.map(stack, dbs.make_state(
-                    n_extents, max_volumes, max_pages)),
-                pool=jnp.zeros((n_shards, n_extents + 1, page_blocks)
-                               + tuple(payload_shape), dtype),
-                page_rev=jnp.zeros((n_shards, max_volumes, max_pages),
-                                   jnp.int32),
-                null_storage=null_storage)
-            for _ in range(n_replicas)]
+        if devices is not None and len(devices) != n_replicas:
+            raise ValueError(f"{len(devices)} devices for {n_replicas} "
+                             "replicas: give one device per replica")
+        self.mesh = (None if devices is None else jax.sharding.Mesh(
+            np.asarray(devices), ("node",)))
+        self.node_arrays: Optional[tuple] = None
+
+        def arrays():
+            # one extra extent row per pool: the fused CoW kernel's
+            # masked-lane dump (same convention as ReplicaGroup)
+            return (jax.tree.map(stack, dbs.make_state(
+                        n_extents, max_volumes, max_pages)),
+                    jnp.zeros((n_shards, n_extents + 1, page_blocks)
+                              + tuple(payload_shape), dtype),
+                    jnp.zeros((n_shards, max_volumes, max_pages), jnp.int32))
+
+        if devices is None:
+            endpoints = [StackedReplica(*arrays(), null_storage=null_storage)
+                         for _ in range(n_replicas)]
+        else:
+            per = [_on_device(d, arrays) for d in devices]
+            self.node_arrays = jax.tree.map(
+                lambda *blocks: _node_global(self.mesh, blocks), *per)
+            endpoints = [_NodeReplica(self, r, null_storage)
+                         for r in range(n_replicas)]
         self.transports = [
             make_transport(self.transport_name, ep,
                            **_transport_opts(transport_opts, i))
@@ -471,7 +496,14 @@ class ShardedReplicaGroup(_Waiter):
         self._healthy_np = np.ones((n_shards, n_replicas), bool)
         self._healthy_dev: Optional[jnp.ndarray] = None   # device-mask cache
         self._healthy_stale = False   # device mask newer than the np mirror
-        self._rr = jnp.zeros((n_shards,), jnp.int32)
+        self._rr = self._replicated(np.zeros((n_shards,), np.int32))
+
+    def _replicated(self, x) -> jnp.ndarray:
+        """``x`` on the default device, or replicated over the mesh."""
+        if self.mesh is None:
+            return jnp.asarray(x)
+        return jax.device_put(x, jax.sharding.NamedSharding(
+            self.mesh, jax.sharding.PartitionSpec()))
 
     # -- the stacked endpoint pytrees (legacy .states/.pools surface) --------
     @property
@@ -537,11 +569,21 @@ class ShardedReplicaGroup(_Waiter):
         copy is cached (health changes only on fail/rebuild control ops) so
         the pump path pays no per-iteration host-to-device transfer."""
         pools = () if self.null_storage else tuple(self.pools)
+        return tuple(self.states), pools, self.device_health()
+
+    def device_health(self) -> jnp.ndarray:
+        """The (S, R) health mask on the device (cached)."""
         if self._healthy_dev is None:
-            self._healthy_dev = jnp.asarray(self.healthy)
-        return tuple(self.states), pools, self._healthy_dev
+            self._healthy_dev = self._replicated(self.healthy)
+        return self._healthy_dev
 
     def set_device_state(self, states, pools) -> None:
+        if self.mesh is not None:
+            # every block at once: the caller may have donated the old ones
+            glob = lambda *blocks: _node_global(self.mesh, blocks)
+            self.node_arrays = (jax.tree.map(glob, *states), glob(*pools),
+                                self.node_arrays[2])
+            return
         for t, st in zip(self.transports, states):
             t.endpoint.state = st
         if pools:
@@ -653,23 +695,83 @@ class ShardedReplicaGroup(_Waiter):
         return True
 
 
+def _on_device(device, make):
+    """Build ``make()``'s arrays directly on ``device`` (committed there),
+    so a pool is never allocated on the default device first."""
+    with jax.default_device(device):
+        return jax.device_put(make(), device)
+
+
+def _node_global(mesh, blocks) -> jax.Array:
+    """One global array over ``mesh``, split ``P("node")``, whose node r
+    block is ``blocks[r]`` (already on node r's device; no copy)."""
+    return jax.make_array_from_single_device_arrays(
+        (len(blocks) * blocks[0].shape[0],) + blocks[0].shape[1:],
+        jax.sharding.NamedSharding(mesh, jax.sharding.PartitionSpec("node")),
+        list(blocks))
+
+
+class _NodeReplica(StackedReplica):
+    """Replica ``r`` of a ``ShardedReplicaGroup`` whose replicas lie on
+    separate chips: its state, pool and watermarks are node r's blocks of
+    the group's ``node_arrays`` (views, no copy), and setting one of them
+    replaces node r's block, the other nodes' blocks kept as they are."""
+
+    def __init__(self, group: ShardedReplicaGroup, r: int,
+                 null_storage: bool):
+        self._group, self._r = group, r
+        self.null_storage = null_storage
+
+    def _block(self, i: int):
+        return jax.tree.map(lambda x: x.addressable_data(self._r),
+                            self._group.node_arrays[i])
+
+    def _put(self, i: int, value) -> None:
+        g = self._group
+
+        def swap(glob, new):
+            blocks = [glob.addressable_data(n) for n in range(g.n_replicas)]
+            blocks[self._r] = new
+            return _node_global(g.mesh, blocks)
+        arrays = list(g.node_arrays)
+        arrays[i] = jax.tree.map(swap, arrays[i], value)
+        g.node_arrays = tuple(arrays)
+
+    state = property(lambda self: self._block(0),
+                     lambda self, v: self._put(0, v))
+    pool = property(lambda self: self._block(1),
+                    lambda self, v: self._put(1, v))
+    page_rev = property(lambda self: self._block(2),
+                        lambda self, v: self._put(2, v))
+
+
 # ---------------------------------------------------------------------------
-# mesh-collective forms (used inside shard_map)
+# mesh-collective forms (used inside shard_map; the ring step over replicas
+# on separate chips, core/ring.py)
 # ---------------------------------------------------------------------------
 def mirror_write(x: jnp.ndarray, axis: str, src_index: int = 0) -> jnp.ndarray:
     """Broadcast a written value from ``src_index`` to all replicas on an
-    axis — write-to-all as a collective."""
+    axis — write-to-all as a collective: one point-to-point permute per
+    destination (a permute may name each source once), bit for bit."""
     n = jax.lax.axis_size(axis)
-    perm = [(src_index, j) for j in range(n) if j != src_index]
-    out = jax.lax.ppermute(x, axis, perm)
     me = jax.lax.axis_index(axis)
-    return jnp.where(me == src_index, x, out)
+    out = x
+    for j in range(n):
+        if j != src_index:
+            got = jax.lax.ppermute(x, axis, [(src_index, j)])
+            out = jnp.where(me == j, got, out)
+    return out
 
 
 def rr_select(x: jnp.ndarray, axis: str, step: jnp.ndarray) -> jnp.ndarray:
     """Read-one-of-N: replica (step % N) contributes, others send zeros; a
-    psum delivers the chosen replica's value everywhere."""
+    psum delivers the chosen replica's value everywhere. 32-bit values are
+    summed as integers, so the value arrives bit for bit."""
     n = jax.lax.axis_size(axis)
     me = jax.lax.axis_index(axis)
     chosen = (step % n) == me
-    return jax.lax.psum(jnp.where(chosen, x, jnp.zeros_like(x)), axis)
+    mine = jnp.where(chosen, x, jnp.zeros_like(x))
+    if x.dtype.itemsize != 4:
+        return jax.lax.psum(mine, axis)
+    bits = jax.lax.bitcast_convert_type(mine, jnp.int32)
+    return jax.lax.bitcast_convert_type(jax.lax.psum(bits, axis), x.dtype)

@@ -58,7 +58,8 @@ from repro.compute.phase import apply_compute_ops
 from repro.core import dbs, slots
 from repro.core.control import ControlDispatch
 from repro.core.fused import _cow_apply, _cow_work, _rr_gather
-from repro.core.replication import ShardedReplicaGroup
+from repro.core.replication import (ShardedReplicaGroup, mirror_write,
+                                    rr_select)
 from repro.core.transport import clone_page_rev, stamp_page_rev
 
 # ---------------------------------------------------------------------------
@@ -355,6 +356,20 @@ def _apply_repl_ops(states, pools, page_revs, healthy, batch: SQE, ok,
     return states, pools, page_revs, healthy, status
 
 
+def _write_ack(pool, held, wlane, status, axis: str):
+    """The acknowledgement across chips: a write lane completes ``ST_OK``
+    only if every replica on the ``axis`` holds it. ``held`` marks the
+    lanes this node's replica wrote; the barrier ties it to the pool the
+    write kernel returned, so the count summed over the nodes — and the
+    status every node emits, node 0's CQE included — exists only once every
+    replica's kernel has run. Returns (pool, status)."""
+    pool, held = jax.lax.optimization_barrier((pool, held.astype(jnp.int32)))
+    with jax.named_scope("replica_ack"):
+        n_held = jax.lax.psum(held, axis)
+    need = jax.lax.axis_size(axis)
+    return pool, jnp.where(wlane & (n_held < need), ST_ERR, status)
+
+
 def ring_step_core(table: slots.SlotTable, cq: CQ,
                    states: Tuple[dbs.DBSState, ...],
                    pools: Tuple[jnp.ndarray, ...],
@@ -362,7 +377,8 @@ def ring_step_core(table: slots.SlotTable, cq: CQ,
                    rr: jnp.ndarray, healthy: jnp.ndarray, *,
                    classes: Tuple[str, ...], null_backend: bool = False,
                    null_storage: bool = False, kernel: str = "pallas",
-                   compute_tail: int = COMPUTE_TAIL):
+                   compute_tail: int = COMPUTE_TAIL,
+                   replica_axis: Optional[str] = None):
     """One ring iteration, un-jitted (vmap-safe over a leading shard axis).
 
     ``classes`` (static) names the opcode classes present in this batch
@@ -374,6 +390,15 @@ def ring_step_core(table: slots.SlotTable, cq: CQ,
     whole on in-band REBUILD. ``cq.work`` accumulates the extent rows and
     calls of every ``dbs_rw_write`` kernel the step runs. Returns
     ``(table', cq', states', pools', page_revs', healthy', CQEView)``.
+
+    ``replica_axis`` names the mesh axis of replicas on separate chips
+    (inside ``shard_map``; RingEngine's ``replica_chips``): the tuples
+    then hold this node's one replica, ``healthy`` still covers all R. The
+    node writes its own replica, a write lane is acknowledged only once
+    every replica holds it (``_write_ack``), and the read lanes'
+    data comes back from the serving replica's node (``rr_select``). The
+    compute and replica-control phases are not lowered that way (the
+    engine refuses those ops at submission).
     """
     table, ids, ok = slots.transact(table, batch.want, batch.volume,
                                     batch.queue, batch.step,
@@ -384,6 +409,9 @@ def ring_step_core(table: slots.SlotTable, cq: CQ,
     reads = jnp.zeros_like(batch.payload)
     work = jnp.zeros((2,), jnp.int32)
 
+    # replica i of the tuples is replica ``rep[i]`` of the health mask
+    rep = (range(len(states)) if replica_axis is None
+           else [jax.lax.axis_index(replica_axis)])
     if not null_backend and states:
         if "write" in classes:                   # mirrored CoW data phase
             wmask = ok & (batch.op == OP_WRITE)
@@ -391,7 +419,7 @@ def ring_step_core(table: slots.SlotTable, cq: CQ,
             out_states, out_pools, out_prs = [], [], []
             for i, st in enumerate(states):
                 st, wops = dbs.write_pages(st, batch.volume, batch.page,
-                                           bits, wmask & healthy[i])
+                                           bits, wmask & healthy[rep[i]])
                 if not null_storage:
                     work = work + _cow_work(pools[i], wops, batch.block,
                                             kernel)
@@ -402,14 +430,26 @@ def ring_step_core(table: slots.SlotTable, cq: CQ,
                         page_revs[i], batch.volume, batch.page, wops.ok,
                         st.revision))
                 out_states.append(st)
+            if replica_axis is not None and not null_storage:
+                out_pools[0], status = _write_ack(
+                    out_pools[0], wops.ok & wmask & healthy[rep[0]], wmask,
+                    status, replica_axis)
             states = tuple(out_states)
             if not null_storage:
                 pools = tuple(out_pools)
                 page_revs = tuple(out_prs)
         if "read" in classes and not null_storage:
-            reads = _rr_gather(states, pools, batch, rr,
-                               ok & (batch.op == OP_READ), reads, healthy,
-                               kernel)
+            rmask = ok & (batch.op == OP_READ)
+            if replica_axis is None:
+                reads = _rr_gather(states, pools, batch, rr, rmask, reads,
+                                   healthy, kernel)
+            else:
+                # this node's replica serves the batch's reads when it is
+                # the round-robin pick; its data returns to every node
+                mine = _rr_gather(states, pools, batch, rr, rmask, reads,
+                                  None, kernel)
+                with jax.named_scope("replica_read_return"):
+                    reads = rr_select(mine, replica_axis, rr)
         if "compute" in classes and not null_storage:
             # in-band storage functions: between data and control (the drain
             # never mixes compute with control lanes, so this phase and the
@@ -688,6 +728,17 @@ class RingEngine(ControlDispatch):
 
     Registered as ``backend="ring"`` in core/backends.py — the only backend
     whose submission path (``data_kinds``) accepts control opcodes.
+
+    ``cfg.replica_chips = R`` (= ``n_replicas``, one shard) puts replica r
+    on ``jax.devices()[r]``: the step is lowered through ``shard_map`` over
+    the one-axis mesh (``"node"``) of the replica chips, each node runs
+    ``ring_step_core`` on its own replica and keeps its own copy of the
+    slot table and the CQ (every node runs the same slot transaction on
+    the same SQEs). The pump still uploads the packed SQE once, to node 0
+    (the engine's chip); the step sends it on to the other nodes over the
+    chips' interconnect (``replication.mirror_write``, named scope
+    ``replica_fanout``), and the CQE view is fetched from node 0 alone.
+    In-band FAIL/REBUILD and COMPUTE are refused at submission there.
     """
 
     is_pool = True
@@ -701,6 +752,8 @@ class RingEngine(ControlDispatch):
             raise ValueError(f"n_shards must be >= 1, got {s}")
         self.cfg = cfg
         self.n_shards = s
+        self.replica_chips = getattr(cfg, "replica_chips", 1)
+        devices = self._replica_devices(cfg)
         self._compute_tail = getattr(cfg, "compute_tail", COMPUTE_TAIL)
         self.frontend = RingFrontend(s, cfg.n_queues, cfg.n_slots, cfg.batch,
                                      compute_tail=self._compute_tail)
@@ -712,8 +765,26 @@ class RingEngine(ControlDispatch):
                 cfg.max_pages, cfg.page_blocks, cfg.payload_shape,
                 null_storage=cfg.null_storage, transport=cfg.transport,
                 write_policy=cfg.write_policy, read_policy=cfg.read_policy,
-                transport_opts=cfg.transport_opts)
+                transport_opts=cfg.transport_opts, devices=devices)
         self.cq = make_sharded_cq(s, cfg.n_slots, cfg.payload_shape)
+        self.mesh = None if devices is None else self.backend.mesh
+        # ops the step is not lowered for on this layout
+        self.refused_kinds = frozenset()
+        # host totals (no device sync): user bytes owed to the remote
+        # replicas, and read lanes a remote replica served
+        self.fanout_bytes = 0
+        self.remote_read_lanes = 0
+        if self.mesh is not None:
+            self.refused_kinds = frozenset({"fail", "rebuild", "compute"})
+            per_node = jax.sharding.NamedSharding(
+                self.mesh, jax.sharding.PartitionSpec("node"))
+            copies = lambda x: np.tile(np.asarray(x),
+                                       (len(devices),) + (1,) * (x.ndim - 1))
+            self.frontend.table = jax.device_put(
+                jax.tree.map(copies, self.frontend.table), per_node)
+            self.cq = jax.device_put(jax.tree.map(copies, self.cq), per_node)
+            self._rr_host = 0            # the read cursor, mirrored
+            self._sqe_fill: Dict[int, List[Any]] = {}
         from repro.kernels.dbs.registry import resolve_kernel_name
         self._kernel = resolve_kernel_name(cfg)
         self._vol_rr = 0
@@ -729,6 +800,51 @@ class RingEngine(ControlDispatch):
         self._work_raw = np.zeros(2, np.int64)
         self.trace_counts: Dict[Tuple[str, ...], int] = {}
         self._steps: Dict[Tuple[str, ...], Any] = {}
+
+    @staticmethod
+    def _replica_devices(cfg) -> Optional[List[Any]]:
+        """The chips of the replicas (None: all on the default device);
+        raises ValueError for a layout the ring cannot lower."""
+        chips = getattr(cfg, "replica_chips", 1)
+        if chips == 1:
+            return None
+        if chips != cfg.n_replicas:
+            raise ValueError(
+                f"replica_chips={chips}: put all replicas on one chip "
+                f"(replica_chips=1) or each on its own "
+                f"(replica_chips=n_replicas={cfg.n_replicas})")
+        if getattr(cfg, "n_shards", 1) != 1:
+            raise ValueError("replica_chips > 1 needs n_shards=1 (one "
+                             "replica group across the chips)")
+        if cfg.null_backend or cfg.null_storage:
+            raise ValueError("replica_chips > 1 needs the storage layers "
+                             "(null_backend / null_storage off)")
+        devices = jax.devices()
+        if len(devices) < chips:
+            raise ValueError(f"replica_chips={chips} but JAX sees "
+                             f"{len(devices)} device(s)")
+        return devices[:chips]
+
+    def node0(self, tree):
+        """``tree`` as node 0 (the engine's chip) holds it: per-node
+        arrays of the replica mesh are cut to node 0's block; on one chip
+        ``tree`` itself."""
+        if self.mesh is None:
+            return tree
+        return jax.tree.map(lambda x: x.addressable_data(0), tree)
+
+    def replica_counters(self) -> Dict[str, int]:
+        """Traffic between the replica chips since the engine was built
+        (host totals, no device sync; 0 with every replica on one chip):
+        ``fanout_bytes``, the user bytes owed to the remote replicas —
+        written blocks x (R - 1) x block bytes, one byte per payload lane,
+        counted when staged; the interconnect carries more, since the step
+        sends the whole packed SQE to every remote node (R - 1 times
+        ``upload_bytes`` / ``upload_transfers`` per step) — and
+        ``remote_read_lanes``, read lanes a replica on another chip than
+        node 0 served."""
+        return {"fanout_bytes": self.fanout_bytes,
+                "remote_read_lanes": self.remote_read_lanes}
 
     # ------------------------------------------------------------ programs
     @staticmethod
@@ -777,6 +893,10 @@ class RingEngine(ControlDispatch):
                        null_storage=self.cfg.null_storage,
                        kernel=self._kernel,
                        compute_tail=self._compute_tail)
+        if self.mesh is not None:
+            fn = self._mesh_step(key, cache_key, core, unpack)
+            self._steps[cache_key] = fn
+            return fn, key
         mapped = vmap_shards(core, self.n_shards)
 
         if read_only:
@@ -804,6 +924,69 @@ class RingEngine(ControlDispatch):
         fn = jax.jit(stepped, donate_argnums=donate)
         self._steps[cache_key] = fn
         return fn, key
+
+    def _mesh_step(self, key: Tuple[str, ...], cache_key, core, unpack):
+        """The tier's program over the replica mesh: ``shard_map`` of one
+        node's step. Node 0 holds the uploaded SQE; the other nodes' input
+        blocks are placeholders that ``mirror_write`` overwrites with it.
+        Every argument and result is split per node (``P("node")``) except
+        the read cursor and the health mask, which all nodes share."""
+        from jax.sharding import PartitionSpec as P
+        read_only = key == ("read",)
+        mapped = vmap_shards(partial(core, replica_axis="node"),
+                             self.n_shards)
+        node = P("node")
+
+        def body(table, cq, state, pool, page_rev, packed, rr, healthy):
+            self.trace_counts[cache_key] += 1
+            with jax.named_scope("replica_fanout"):
+                packed = mirror_write(packed, "node", 0)
+            table, cq, (state,), (pool,), (page_rev,), _, view = mapped(
+                table, cq, (state,), (pool,), (page_rev,), unpack(packed),
+                rr, healthy)
+            if read_only:
+                return table, cq, view
+            return table, cq, state, pool, page_rev, view
+
+        n_out = 3 if read_only else 6
+        stepped = jax.shard_map(
+            body, mesh=self.mesh, in_specs=(node,) * 6 + (P(), P()),
+            out_specs=(node,) * n_out, check_vma=False)
+        stepped.__name__ = stepped.__qualname__ = "ring_step_" + "_".join(key)
+        return jax.jit(stepped,
+                       donate_argnums=(0, 1) if read_only else (0, 1, 2, 3, 4))
+
+    def _mesh_sqe(self, packed):
+        """The uploaded (S, W) SQE as node 0's block of a (R*S, W) array
+        split over the mesh; the other nodes' blocks are zeros kept on
+        their chips per width (never donated, so allocated once)."""
+        w = packed.shape[1]
+        fill = self._sqe_fill.get(w)
+        if fill is None:
+            fill = self._sqe_fill[w] = [
+                jax.device_put(np.zeros(packed.shape, np.int32), d)
+                for d in self.mesh.devices.flat[1:]]
+        parts = [jax.device_put(packed, self.mesh.devices.flat[0])] + fill
+        return jax.make_array_from_single_device_arrays(
+            (len(parts) * packed.shape[0], w),
+            jax.sharding.NamedSharding(self.mesh,
+                                       jax.sharding.PartitionSpec("node")),
+            parts)
+
+    def _count_replica_traffic(self, reqs) -> None:
+        """``replica_counters``' host totals for one dispatched step."""
+        n_write = n_read = 0
+        for shard_reqs in reqs:
+            for r in shard_reqs:
+                n_write += r.kind == "write"
+                n_read += r.kind == "read"
+        n_rep = self.replica_chips
+        self.fanout_bytes += (n_write * (n_rep - 1)
+                              * int(np.prod(self.cfg.payload_shape)))
+        # every replica is healthy on this layout: rr % R serves the reads
+        if self._rr_host % n_rep:
+            self.remote_read_lanes += n_read
+        self._rr_host += 1
 
     # ------------------------------------------------------------ volumes
     def create_volume(self) -> int:
@@ -899,10 +1082,18 @@ class RingEngine(ControlDispatch):
         return self.frontend.depth()
 
     # ------------------------------------------------------------- pumping
+    def refuse(self, kind: str) -> None:
+        """Raise ValueError for a kind of op this layout does not run."""
+        if kind in self.refused_kinds:
+            raise ValueError(
+                f"kind={kind!r} is not supported with the replicas on "
+                f"separate chips (replica_chips={self.replica_chips}) yet")
+
     def submit(self, req) -> None:
         if req.kind not in self.data_kinds:
             raise ValueError(f"unknown request kind {req.kind!r} "
                              f"(expected one of {sorted(self.data_kinds)})")
+        self.refuse(req.kind)
         self.frontend.submit(req)
 
     def pump_async(self) -> Optional[PendingRing]:
@@ -918,6 +1109,8 @@ class RingEngine(ControlDispatch):
         self.upload_payload_skips += (
             packed.shape[1] == header_words(self.frontend.batch))
         self.dispatches += 1
+        if self.mesh is not None:
+            return self._pump_mesh(reqs, packed, classes)
         with TraceAnnotation("ring.dispatch", step=self.dispatches):
             if self.backend is None:
                 states, pools, page_revs = (), (), ()
@@ -946,6 +1139,28 @@ class RingEngine(ControlDispatch):
                         self.backend.adopt_health(healthy)
             self.frontend.table = table
             self.cq = cq
+        return PendingRing(reqs=reqs, view=view, step=self.dispatches)
+
+    def _pump_mesh(self, reqs, packed, classes) -> PendingRing:
+        """``pump_async``'s dispatch on the replica mesh: the launch on the
+        replicas' global arrays (``ShardedReplicaGroup.node_arrays``),
+        which take the step's outputs as they are, and node 0's CQE view
+        kept for the fetch."""
+        self._count_replica_traffic(reqs)
+        with TraceAnnotation("ring.dispatch", step=self.dispatches):
+            backend = self.backend
+            rr = backend.bump_rr()
+            step, key = self._get_step(classes)
+            args = (self.frontend.table, self.cq, *backend.node_arrays,
+                    self._mesh_sqe(packed), rr, backend.device_health())
+            if key == ("read",):
+                table, cq, view = step(*args)
+            else:
+                table, cq, *arrays, view = step(*args)
+                backend.node_arrays = tuple(arrays)
+            self.frontend.table = table
+            self.cq = cq
+            view = self.node0(view)
         return PendingRing(reqs=reqs, view=view, step=self.dispatches)
 
     def _complete(self, p: PendingRing) -> int:

@@ -157,3 +157,84 @@ def test_ring_step_programs_are_named_by_their_tier(one_chip, monkeypatch):
         assert "module @jit_ring_step_" + "_".join(key) in text
         assert ('kernel_name = "dbs_rw_write"' in text) == ("write" in key)
         assert 'kernel_name = "dbs_rw_read"' in text
+
+
+@pytest.fixture(scope="module")
+def three_chips():
+    """A one-axis ("node") mesh over three chips of a described v5e:2x2."""
+    import numpy as np
+    from jax.experimental import topologies
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setenv("TPU_LOG_DIR", "disabled")
+        try:
+            topo = topologies.get_topology_desc(platform="tpu",
+                                                topology_name="v5e:2x2")
+        except Exception as e:  # noqa: BLE001 — any failure: no topology
+            pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+        yield jax.sharding.Mesh(np.array(topo.devices[:3]), ("node",))
+
+
+def test_ring_step_compiles_over_three_chips(three_chips, monkeypatch):
+    """The ring step with each replica on its own chip (replica_chips=3),
+    compiled for three chips of a v5e:2x2: the uploaded SQE reaches the
+    other nodes through collective permutes, the acknowledgement and the
+    read return are all-reduces, the kernels are there, and each chip's
+    pool is updated in place. The step's arguments are the one-chip
+    layout's, restacked as the replica mesh holds them."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+    from repro.core.blockdev import VolumeManager
+    from repro.kernels.dbs import ops
+
+    mgr = VolumeManager(backend="ring", n_replicas=3, payload_elems=128,
+                        page_blocks=8, n_extents=16, max_pages=4, batch=8,
+                        kernel="pallas")
+    impl = mgr.engine.impl
+    get_step = impl._get_step
+    captured = {}
+
+    def spy(classes):
+        fn, key = get_step(classes)
+
+        def record(*args):
+            captured[key] = jax.tree.map(
+                lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype), args)
+            return fn(*args)
+        return record, key
+
+    monkeypatch.setattr(impl, "_get_step", spy)
+    vol = mgr.create()
+    vol.write(0, bytes(range(128)))
+    assert vol.read(0, 128) == bytes(range(128))
+    monkeypatch.setattr(impl, "_get_step", get_step)
+    monkeypatch.setattr(ops, "default_interpret", lambda: False)
+    monkeypatch.setattr(impl, "mesh", three_chips)
+    impl._steps.clear()
+    node = NamedSharding(three_chips, P("node"))
+    shared = NamedSharding(three_chips, P())
+
+    def per_node(x, n=3):
+        return jax.ShapeDtypeStruct((n * x.shape[0],) + x.shape[1:],
+                                    x.dtype, sharding=node)
+
+    for key in (("read", "write"), ("read",)):
+        table, cq, states, pools, page_revs, packed, rr, healthy = \
+            captured[key]
+        args = (jax.tree.map(per_node, table), jax.tree.map(per_node, cq),
+                jax.tree.map(per_node, states[0]),
+                per_node(pools[0]), per_node(page_revs[0]), per_node(packed),
+                jax.ShapeDtypeStruct(rr.shape, rr.dtype, sharding=shared),
+                jax.ShapeDtypeStruct(healthy.shape, healthy.dtype,
+                                     sharding=shared))
+        fn, _ = impl._get_step(set(key))
+        compiled = fn.lower(*args).compile()
+        text = compiled.as_text()
+        assert "module_name=jit_ring_step_" + "_".join(key) in text \
+            or "HloModule jit_ring_step_" + "_".join(key) in text
+        assert "collective-permute-start" in text
+        assert "source_target_pairs={{0,1}}" in text
+        assert "source_target_pairs={{0,2}}" in text
+        assert "all-reduce" in text
+        assert "tpu_custom_call" in text
+        mem = compiled.memory_analysis()
+        if key == ("read", "write"):
+            assert mem.alias_size_in_bytes >= pools[0].size * 4
