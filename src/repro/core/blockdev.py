@@ -505,6 +505,10 @@ class VolumeManager:
           host-to-device transfer per dispatched step, their bytes, and
           those that left the payload out because the step's program was
           read-only (``RingEngine.upload_counters``; no device sync);
+        - on ``backend="ring"``, ``fetch_transfers`` and ``fetch_bytes``:
+          the pump's packed CQE fetches, one device-to-host transfer per
+          completed step, and their bytes (``RingEngine.fetch_counters``;
+          no device sync);
         - on ``backend="ring"``, ``fanout_bytes`` and ``remote_read_lanes``:
           with the replicas on separate chips (``replica_chips``), the
           user bytes owed to the remote replicas (written blocks x (R - 1)
@@ -528,6 +532,7 @@ class VolumeManager:
         if hasattr(impl, "work_counters"):
             out.update(impl.work_counters())
             out.update(impl.upload_counters())
+            out.update(impl.fetch_counters())
             out.update(impl.replica_counters())
         table = getattr(self.engine.frontend, "table", None)
         if table is not None:

@@ -16,7 +16,7 @@ protocol. This module is the io_uring-style fix:
 - **CQ** — a device-resident buffer of completion records indexed by slot id
   (the "payload slot"): status, op result value, op latency in pump ticks,
   and the read payload. The step scatters a CQE per admitted lane; the host
-  performs its usual single per-pump fetch of the per-lane view.
+  fetches the per-lane view once per pump, packed into one int32 array.
 - **ring_step_core** — the opcode-dispatched engine iteration: the batched
   data phase (mirrored CoW writes, rr reads — identical to fused.step_core),
   then a lane-ordered ``lax.scan`` applying the volume-control tail
@@ -194,12 +194,41 @@ class CQ:
 @dataclass
 class CQEView:
     """The per-lane view of this pump's completion records — what the host's
-    single per-pump ``device_get`` fetches."""
+    single per-pump ``device_get`` fetches, packed (``pack_cqe``)."""
     ok: jnp.ndarray         # (B,) bool  lane admitted (and thus completed)
     status: jnp.ndarray     # (B,) int32
     value: jnp.ndarray      # (B,) int32
     latency: jnp.ndarray    # (B,) int32
     reads: jnp.ndarray      # (B, *payload)
+
+
+# The packed fetch: one int32 row per lane carries the lane's whole CQE —
+# the fields below in this order (``ok`` as 0/1), then the read payload's
+# float32 bits, prod(payload_shape) words (one for a scalar payload).
+CQE_LANES = ("ok", "status", "value", "latency")
+
+
+def pack_cqe(view: CQEView) -> jnp.ndarray:
+    """The stacked (S, B) CQE view as one (S, B, 4 + P) int32 array, inside
+    the jitted step, so the host fetches it in one device-to-host transfer.
+    The payload is bit-cast, not converted: NaN payload bits survive."""
+    s_n, b_n = view.ok.shape
+    reads = jax.lax.bitcast_convert_type(view.reads, jnp.int32)
+    lanes = [getattr(view, name).astype(jnp.int32)[..., None]
+             for name in CQE_LANES]
+    return jnp.concatenate(lanes + [reads.reshape(s_n, b_n, -1)], axis=-1)
+
+
+def unpack_cqe(buf: np.ndarray,
+               payload_shape: Tuple[int, ...]) -> CQEView:
+    """The fetched (S, B, 4 + P) int32 buffer as the stacked CQE view, by
+    slicing and viewing its memory: every field but the (S, B) ``ok``
+    comparison is a view of ``buf``, the payload viewed as float32."""
+    lanes = {name: buf[..., k] for k, name in enumerate(CQE_LANES)}
+    lanes["ok"] = lanes["ok"] != 0
+    reads = buf[..., len(CQE_LANES):].view(np.float32).reshape(
+        buf.shape[:2] + tuple(payload_shape))
+    return CQEView(reads=reads, **lanes)
 
 
 def make_cq(n_slots: int, payload_shape: Tuple[int, ...] = ()) -> CQ:
@@ -697,11 +726,12 @@ class RingFrontend:
 # ---------------------------------------------------------------------------
 @dataclass
 class PendingRing:
-    """Completion handle from ``pump_async``: the per-lane CQE view (device
-    futures) plus the host-side request lists that rode the batch, and the
-    step's number (``RingEngine.dispatches`` when it launched)."""
+    """Completion handle from ``pump_async``: the step's packed CQE view
+    (``pack_cqe``; a device future) plus the host-side request lists that
+    rode the batch, and the step's number (``RingEngine.dispatches`` when
+    it launched)."""
     reqs: List[List[Any]]
-    view: CQEView
+    cqe: jax.Array
     step: int
 
 
@@ -723,8 +753,9 @@ class RingEngine(ControlDispatch):
     ``ring.upload`` (its single host-to-device transfer, the header alone
     for the read tier), ``ring.dispatch`` (device state, program lookup,
     launch, state hand-back; ``step=`` its number),
-    ``ring.fetch`` (the blocking fetch of the step's CQE view; ``step=`` the
-    step it fetches) and ``ring.deliver`` (per-lane completion, requeues).
+    ``ring.fetch`` (the blocking fetch of the step's CQE view, one packed
+    device-to-host transfer; ``step=`` the step it fetches) and
+    ``ring.deliver`` (per-lane completion, requeues).
 
     Registered as ``backend="ring"`` in core/backends.py — the only backend
     whose submission path (``data_kinds``) accepts control opcodes.
@@ -737,7 +768,8 @@ class RingEngine(ControlDispatch):
     the same SQEs). The pump still uploads the packed SQE once, to node 0
     (the engine's chip); the step sends it on to the other nodes over the
     chips' interconnect (``replication.mirror_write``, named scope
-    ``replica_fanout``), and the CQE view is fetched from node 0 alone.
+    ``replica_fanout``), and the packed CQE view is fetched from node 0
+    alone.
     In-band FAIL/REBUILD and COMPUTE are refused at submission there.
     """
 
@@ -795,6 +827,9 @@ class RingEngine(ControlDispatch):
         self.upload_transfers = 0
         self.upload_bytes = 0
         self.upload_payload_skips = 0
+        # host totals of the packed CQE fetches (``fetch_counters``)
+        self.fetch_transfers = 0
+        self.fetch_bytes = 0
         # host totals of CQ.work and the last raw (wrapping) device reading
         self._work = np.zeros(2, np.int64)
         self._work_raw = np.zeros(2, np.int64)
@@ -910,14 +945,15 @@ class RingEngine(ControlDispatch):
                 table, cq, _, _, _, _, view = mapped(
                     table, cq, states, pools, page_revs, unpack(packed), rr,
                     healthy)
-                return table, cq, view
+                return table, cq, pack_cqe(view)
             donate = (0, 1)
         else:
             def stepped(table, cq, states, pools, page_revs, packed, rr,
                         healthy):
                 self.trace_counts[cache_key] += 1
-                return mapped(table, cq, states, pools, page_revs,
-                              unpack(packed), rr, healthy)
+                *out, view = mapped(table, cq, states, pools, page_revs,
+                                    unpack(packed), rr, healthy)
+                return (*out, pack_cqe(view))
             donate = (0, 1, 2, 3, 4)
         # the tier names the program (jit_ring_step_read_write, ...)
         stepped.__name__ = stepped.__qualname__ = "ring_step_" + "_".join(key)
@@ -944,9 +980,10 @@ class RingEngine(ControlDispatch):
             table, cq, (state,), (pool,), (page_rev,), _, view = mapped(
                 table, cq, (state,), (pool,), (page_rev,), unpack(packed),
                 rr, healthy)
+            cqe = pack_cqe(view)
             if read_only:
-                return table, cq, view
-            return table, cq, state, pool, page_rev, view
+                return table, cq, cqe
+            return table, cq, state, pool, page_rev, cqe
 
         n_out = 3 if read_only else 6
         stepped = jax.shard_map(
@@ -1122,10 +1159,10 @@ class RingEngine(ControlDispatch):
                 rr = self.backend.bump_rr()
             step, key = self._get_step(classes)
             if key == ("read",):
-                table, cq, view = step(self.frontend.table, self.cq, states,
-                                       pools, page_revs, packed, rr, healthy)
+                table, cq, cqe = step(self.frontend.table, self.cq, states,
+                                      pools, page_revs, packed, rr, healthy)
             else:
-                table, cq, states, pools, page_revs, healthy, view = step(
+                table, cq, states, pools, page_revs, healthy, cqe = step(
                     self.frontend.table, self.cq, states, pools, page_revs,
                     packed, rr, healthy)
                 if self.backend is not None:
@@ -1139,13 +1176,13 @@ class RingEngine(ControlDispatch):
                         self.backend.adopt_health(healthy)
             self.frontend.table = table
             self.cq = cq
-        return PendingRing(reqs=reqs, view=view, step=self.dispatches)
+        return PendingRing(reqs=reqs, cqe=cqe, step=self.dispatches)
 
     def _pump_mesh(self, reqs, packed, classes) -> PendingRing:
         """``pump_async``'s dispatch on the replica mesh: the launch on the
         replicas' global arrays (``ShardedReplicaGroup.node_arrays``),
-        which take the step's outputs as they are, and node 0's CQE view
-        kept for the fetch."""
+        which take the step's outputs as they are, and node 0's packed CQE
+        view kept for the fetch."""
         self._count_replica_traffic(reqs)
         with TraceAnnotation("ring.dispatch", step=self.dispatches):
             backend = self.backend
@@ -1154,23 +1191,27 @@ class RingEngine(ControlDispatch):
             args = (self.frontend.table, self.cq, *backend.node_arrays,
                     self._mesh_sqe(packed), rr, backend.device_health())
             if key == ("read",):
-                table, cq, view = step(*args)
+                table, cq, cqe = step(*args)
             else:
-                table, cq, *arrays, view = step(*args)
+                table, cq, *arrays, cqe = step(*args)
                 backend.node_arrays = tuple(arrays)
             self.frontend.table = table
             self.cq = cq
-            view = self.node0(view)
-        return PendingRing(reqs=reqs, view=view, step=self.dispatches)
+            cqe = self.node0(cqe)
+        return PendingRing(reqs=reqs, cqe=cqe, step=self.dispatches)
 
     def _complete(self, p: PendingRing) -> int:
-        """The pump's single host hop: fetch the per-lane CQE view, deliver
-        result/status/latency, requeue not-admitted requests."""
-        v = p.view
+        """The pump's single host hop: fetch the packed per-lane CQE view
+        (one device-to-host transfer), deliver result/status/latency,
+        requeue not-admitted requests."""
         with TraceAnnotation("ring.fetch", step=p.step):
-            ok, status, value, latency, reads = jax.device_get(
-                (v.ok, v.status, v.value, v.latency, v.reads))
+            buf = jax.device_get(p.cqe)
+        self.fetch_transfers += 1
+        self.fetch_bytes += buf.nbytes
         with TraceAnnotation("ring.deliver"):
+            v = unpack_cqe(buf, self.cfg.payload_shape)
+            ok, status, value, latency, reads = (v.ok, v.status, v.value,
+                                                 v.latency, v.reads)
             done = 0
             requeues = []
             for s, shard_reqs in enumerate(p.reqs):
@@ -1221,6 +1262,13 @@ class RingEngine(ControlDispatch):
         return {"upload_transfers": self.upload_transfers,
                 "upload_bytes": self.upload_bytes,
                 "upload_payload_skips": self.upload_payload_skips}
+
+    def fetch_counters(self) -> Dict[str, int]:
+        """The packed CQE fetches since the engine was built (host totals,
+        no device sync): ``fetch_transfers`` (one device-to-host transfer
+        per completed step) and ``fetch_bytes``."""
+        return {"fetch_transfers": self.fetch_transfers,
+                "fetch_bytes": self.fetch_bytes}
 
     def pump(self) -> int:
         p = self.pump_async()

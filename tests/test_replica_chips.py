@@ -4,8 +4,8 @@ a mesh of the replica chips. Checked here on four virtual CPU devices
 (``XLA_FLAGS`` must be set before jax is imported, so the scenario runs in
 a child python, once for the module): the byte API and every replica's
 pool against the bytearray oracle and against the same seeded run with
-all replicas on one device, the placement, the one upload per pump, the
-fan-out counters, the acknowledgement's dependence on every replica's
+all replicas on one device, the placement, the one upload and the one
+fetch per pump, the fan-out counters, the acknowledgement's dependence on every replica's
 write, and the layouts and ops the ring refuses.
 """
 import json
@@ -118,6 +118,7 @@ CHILD = textwrap.dedent("""
             bad_reads=bad_reads, reads=len(reads), bad_api=bad_api,
             bad_replica=bad_replica, where=where, raw=raw,
             dispatches=impl.dispatches, uploads=s["upload_transfers"],
+            fetches=s["fetch_transfers"], fetch_bytes=s["fetch_bytes"],
             fanout_phase=s1["fanout_bytes"] - s0["fanout_bytes"],
             blocks_phase=blocks, fanout=s["fanout_bytes"],
             remote_reads=s["remote_read_lanes"], slots=s["slots_active"],
@@ -192,7 +193,11 @@ CHILD = textwrap.dedent("""
     impl._get_step = get_step
     fn, args = seen[("read", "write")]
     body = find(jax.make_jaxpr(fn)(*args).jaxpr, "shard_map").params["jaxpr"]
-    status = body.outvars[-4]                   # CQEView: ok status value ..
+    # the packed CQE (ring.pack_cqe) is the body's last output, one
+    # concatenate of the lanes ok, status, value, latency and the reads
+    pack = next(e for e in body.eqns if body.outvars[-1] in e.outvars)
+    assert pack.primitive.name == "concatenate" and len(pack.invars) == 5
+    status = pack.invars[1]
     up = ancestors(body, status)
     out["ack_psum"] = any(e.primitive.name == "psum" for e in up)
     out["ack_barrier"] = any(e.primitive.name == "optimization_barrier"
@@ -288,6 +293,15 @@ def test_one_upload_per_pump(res):
         assert r["uploads"] == r["dispatches"] > 0
     assert res["three"]["dispatches"] == res["one"]["dispatches"]
     assert res["three"]["slots"] == res["one"]["slots"] == 0
+
+
+def test_one_fetch_per_pump(res):
+    """Node 0's packed CQE view is fetched once per step: the same count
+    and bytes as with every replica on one chip."""
+    for layout in ("one", "three"):
+        r = res[layout]
+        assert r["fetches"] == r["dispatches"] > 0
+    assert res["three"]["fetch_bytes"] == res["one"]["fetch_bytes"] > 0
 
 
 def test_fanout_counts_the_written_blocks_twice(res):

@@ -21,6 +21,9 @@ Contracts:
 6. **packed upload** — a pump sends its SQE as one host-to-device transfer
    (header only for the read tier) and the step's results match the
    leaf-by-leaf SQE bit for bit, float payload bits included.
+7. **packed fetch** — a pump fetches its CQE view as one device-to-host
+   transfer of one int32 array, which unpacks to the step's five-leaf view
+   bit for bit.
 """
 import dataclasses
 
@@ -694,15 +697,16 @@ def _odd(i: int) -> np.ndarray:
     return lanes
 
 
-def _tier_traffic(tier, vols, base):
+def _tier_traffic(tier, vols, base, odd=_odd):
     """Requests whose batch runs ``tier``'s program: reads of pages the
-    warm-up wrote, plus the writes and control/compute ops the tier adds."""
+    warm-up wrote, plus the writes (payloads ``odd(i)``) and control/compute
+    ops the tier adds."""
     reqs = [Request(req_id=base + i, kind="read", volume=vols[i % 2],
                     page=i % 4, block=i % 8) for i in range(4)]
     if tier != "read":
         reqs += [Request(req_id=base + 10 + i, kind="write",
                          volume=vols[i % 2], page=4 + i, block=i % 8,
-                         payload=_odd(base + i)) for i in range(4)]
+                         payload=odd(base + i)) for i in range(4)]
     if tier == "compute":
         reqs += [Request(req_id=base + 20, kind="compute", fn="checksum",
                          volume=vols[0], page=0, block=2),
@@ -726,27 +730,18 @@ def _same_bits(got, want, what):
         assert g.tobytes() == w.tobytes(), what
 
 
-@pytest.mark.parametrize("shards", [1, 2])
-@pytest.mark.parametrize("tier", ["read", "write", "compute", "vol", "repl"])
-def test_packed_upload_matches_the_eleven_leaf_sqe(tier, shards,
-                                                   monkeypatch):
-    """One pump through the packed upload leaves the same CQE view, CQ,
-    slot table, replica states, pools, watermarks and health, bit for bit,
-    as the step run on the pre-pump state with the SQE uploaded leaf by leaf
-    from ``_stage``'s named views."""
+def _pump_against_the_leaf_sqe(eng, traffic, monkeypatch):
+    """Submit ``traffic`` and run one pump through the packed upload; also
+    run the step on the pre-pump state with the SQE uploaded leaf by leaf
+    from ``_stage``'s named views. Returns the pump's handle, its program
+    key, what the pump left (table, CQ, states, pools, watermarks,
+    health) and the reference step's outputs."""
     from functools import partial
 
     from repro.core import ring
-    eng = Engine(_cfg(n_shards=shards, n_queues=1))
     pool, fe = eng.pool, eng.pool.frontend
-    vols = [eng.create_volume() for _ in range(2)]
-    for i in range(8):
-        eng.submit(Request(req_id=100 + i, kind="write", volume=vols[i % 2],
-                           page=i % 4, block=i % 8, payload=_odd(i)))
-    assert eng.drain() == 8
-    for r in _tier_traffic(tier, vols, 1000):
+    for r in traffic:
         eng.submit(r)
-
     staged = []
     stage = fe._stage
     monkeypatch.setattr(fe, "_stage",
@@ -758,7 +753,6 @@ def test_packed_upload_matches_the_eleven_leaf_sqe(tier, shards,
                                      backend._rr, healthy))
     p = pool.pump_async()
     key = pool._canon(staged[0][2])
-    assert tier in key
     st = staged[0][1]
     leaves = ring.SQE(**{k: jnp.asarray(st[k])
                          for k in ring.SQE_LANES + ("payload", "step")})
@@ -766,25 +760,98 @@ def test_packed_upload_matches_the_eleven_leaf_sqe(tier, shards,
     core = partial(ring.ring_step_core, classes=key, kernel=pool._kernel,
                    compute_tail=pool._compute_tail)
     table0, cq0, states0, pools0, prs0, rr0, healthy0 = before
-    ref = jax.jit(ring.vmap_shards(core, shards))(
+    ref = jax.jit(ring.vmap_shards(core, pool.n_shards))(
         table0, cq0, states0, pools0, prs0, leaves, rr0, healthy0)
     states1, pools1, healthy1 = backend.device_state()
     got = (fe.table, pool.cq, states1, pools1, backend.device_page_revs(),
-           healthy1, p.view)
+           healthy1)
+    return p, key, got, ref
+
+
+@pytest.mark.parametrize("shards", [1, 2])
+@pytest.mark.parametrize("tier", ["read", "write", "compute", "vol", "repl"])
+def test_packed_upload_matches_the_eleven_leaf_sqe(tier, shards,
+                                                   monkeypatch):
+    """One pump through the packed upload leaves the same CQE view, CQ,
+    slot table, replica states, pools, watermarks and health, bit for bit,
+    as the step run on the pre-pump state with the SQE uploaded leaf by leaf
+    from ``_stage``'s named views."""
+    from repro.core import ring
+    eng = Engine(_cfg(n_shards=shards, n_queues=1))
+    vols = [eng.create_volume() for _ in range(2)]
+    for i in range(8):
+        eng.submit(Request(req_id=100 + i, kind="write", volume=vols[i % 2],
+                           page=i % 4, block=i % 8, payload=_odd(i)))
+    assert eng.drain() == 8
+    p, key, got, ref = _pump_against_the_leaf_sqe(
+        eng, _tier_traffic(tier, vols, 1000), monkeypatch)
+    assert tier in key
+    view = ring.unpack_cqe(np.asarray(jax.device_get(p.cqe)), PAY)
     for name, g, w in zip(("table", "cq", "states", "pools", "page_revs",
-                           "healthy", "view"), got, ref):
+                           "healthy", "view"), got + (view,), ref):
         _same_bits(g, w, name)
     # the comparison is not vacuous: the reads returned the warm-up's odd
     # float lanes, NaN payload bits included
-    reads = np.asarray(p.view.reads).view(np.uint32)
+    reads = np.asarray(view.reads).view(np.uint32)
     assert (reads == 0x7FC00123).any() and (reads == 0xFFC0BEEF).any()
 
 
+# ---------------------------------------------------------------------------
+# 7. the packed CQE fetch: one device-to-host transfer per pump
+# ---------------------------------------------------------------------------
+_QUIET_NANS = (0x7FC00123, 0xFFC0BEEF)      # NaNs that carry payload bits
+
+
+@pytest.mark.parametrize("payload", [(), (4096,)], ids=["scalar", "4k"])
+@pytest.mark.parametrize("shards", [1, 2])
+@pytest.mark.parametrize("tier", ["read", "write", "vol"])
+def test_packed_cqe_matches_the_five_leaf_view(tier, shards, payload,
+                                               monkeypatch):
+    """``unpack_cqe`` of the pump's one packed fetch equals, bit for bit,
+    the ``CQEView`` that ``ring_step_core`` returns for the same step: lanes
+    not admitted, negative statuses and values, NaN payload bits."""
+    from repro.core import ring
+
+    def odd(i):
+        lanes = _odd(i)
+        return np.resize(lanes, payload) if payload else lanes[5 + i % 3]
+
+    eng = Engine(_cfg(n_shards=shards, n_queues=1, payload_shape=payload,
+                      n_extents=32, max_pages=8, page_blocks=8))
+    vols = [eng.create_volume() for _ in range(2)]
+    for i in range(8):
+        eng.submit(Request(req_id=100 + i, kind="write", volume=vols[i % 2],
+                           page=i % 4, block=i % 8, payload=odd(i)))
+    assert eng.drain() == 8
+    traffic = _tier_traffic(tier, vols, 1000, odd)
+    if tier == "vol":                        # a volume never created
+        traffic.append(Request(req_id=1100, kind="snapshot",
+                               volume=4 * shards))
+    p, key, got, ref = _pump_against_the_leaf_sqe(eng, traffic, monkeypatch)
+    assert tier in key
+    assert p.cqe.dtype == jnp.int32
+    assert p.cqe.shape == (shards, 16, len(ring.CQE_LANES)
+                           + int(np.prod(payload)))
+    buf = np.asarray(jax.device_get(p.cqe))
+    view = ring.unpack_cqe(buf, payload)
+    _same_bits(view, ref[-1], "view")
+    bits = np.asarray(view.reads).view(np.uint32)
+    assert np.isin(bits, _QUIET_NANS).any()
+    assert (~view.ok).any() and view.ok.any()
+    assert (view.value < 0).any()
+    assert (view.status < 0).any() == (tier == "vol")
+    # the unpacked fields are views of the fetched buffer, not copies
+    for f in ("status", "value", "latency", "reads"):
+        assert np.shares_memory(getattr(view, f), buf), f
+
+
 class _Counting:
-    """A module stand-in that records calls of some of its functions."""
+    """A module stand-in that records calls of some of its functions, and
+    their positional arguments in ``args``."""
 
     def __init__(self, module, names, calls):
         self._module, self._names, self._calls = module, names, calls
+        self.args = []
 
     def __getattr__(self, name):
         fn = getattr(self._module, name)
@@ -793,6 +860,7 @@ class _Counting:
 
         def counted(*a, **kw):
             self._calls.append(name)
+            self.args.append(a)
             return fn(*a, **kw)
         return counted
 
@@ -835,3 +903,43 @@ def test_a_pump_makes_one_host_to_device_transfer(shards, monkeypatch):
                 - c0["upload_payload_skips"]) == skips, kind
     assert pool.trace_counts == {("read", "write"): 1, ("read",): 1}
     assert pool.upload_transfers == pool.dispatches
+
+
+@pytest.mark.parametrize("shards", [1, 2])
+def test_a_pump_makes_one_device_to_host_transfer(shards, monkeypatch):
+    """``_complete`` fetches the step's CQE view with one ``device_get`` of
+    one array, in the read tier and in the write tier."""
+    from repro.core import ring
+    eng = Engine(_cfg(n_shards=shards))
+    pool = eng.pool
+    vols = [eng.create_volume() for _ in range(2)]
+
+    def submit(kind, base):
+        for i in range(6):
+            eng.submit(Request(req_id=base + i, kind=kind,
+                               volume=vols[i % 2], page=i, block=i % 8,
+                               payload=_odd(i) if kind == "write" else None))
+
+    submit("write", 0)
+    assert eng.drain() == 6
+    submit("read", 10)
+    assert eng.drain() == 6                  # both programs compiled
+    calls = []
+    spy = _Counting(jax, {"device_get"}, calls)
+    monkeypatch.setattr(ring, "jax", spy)
+    nbytes = shards * 16 * (len(ring.CQE_LANES) + PAY[0]) * 4
+    for kind in ("write", "read"):
+        c0 = pool.fetch_counters()
+        d0 = pool.dispatches
+        submit(kind, 100)
+        calls.clear()
+        spy.args.clear()
+        assert pool.pump() == 6
+        assert calls == ["device_get"], kind
+        (fetched,), = spy.args
+        assert isinstance(fetched, jax.Array), kind
+        c1 = pool.fetch_counters()
+        assert pool.dispatches - d0 == 1
+        assert c1["fetch_transfers"] - c0["fetch_transfers"] == 1
+        assert c1["fetch_bytes"] - c0["fetch_bytes"] == nbytes, kind
+    assert pool.fetch_transfers == pool.dispatches

@@ -132,11 +132,12 @@ def test_write_counters_take_deltas_modulo_2_32():
 
 
 UPLOADS = {"upload_transfers", "upload_bytes", "upload_payload_skips"}
+FETCHES = {"fetch_transfers", "fetch_bytes"}
 
 
 @pytest.mark.parametrize("backend,keys", [
     ("ring", {"fence_flushes", "fence_steps", "write_rows",
-              "write_kernel_calls"} | UPLOADS),
+              "write_kernel_calls"} | UPLOADS | FETCHES),
     # the fused step counts no steps and has no CQ
     ("fused", {"fence_flushes"}),
 ])
@@ -149,13 +150,16 @@ def test_stats_carry_the_counters(backend, keys):
     mgr.flush()
     s = mgr.stats()
     assert set(s) & ({"fence_flushes", "fence_steps", "write_rows",
-                      "write_kernel_calls"} | UPLOADS) == keys
+                      "write_kernel_calls"} | UPLOADS | FETCHES) == keys
     assert s["fence_flushes"] == 1
     assert s.get("fence_steps", 1) == 1
     if UPLOADS <= keys:                      # one upload per step, all writes
         assert s["upload_transfers"] == mgr.engine.impl.dispatches
         assert s["upload_payload_skips"] == 0
         assert s["upload_bytes"] > 0
+    if FETCHES <= keys:                      # one fetch per completed step
+        assert s["fetch_transfers"] == mgr.engine.impl.dispatches
+        assert s["fetch_bytes"] > 0
 
 
 # ------------------------------------------------------------ the spans
